@@ -24,6 +24,8 @@ def main() -> None:
                     help="run a single benchmark by name")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (affinity, bfs_algorithms, bfs_batched,
                             bfs_formats, bfs_layers, bfs_megakernel,
                             bfs_opt_ablation, bfs_packed,

@@ -14,6 +14,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import csr as csr_mod
 from repro.core import rmat
 from repro.core.bfs_parallel import run_bfs
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--algorithm", default="vectorized",
                     choices=["vectorized", "simd", "nonsimd"])
     args = ap.parse_args()
+    compile_cache.enable()
 
     print(f"== Graph500 kernel 1: SCALE={args.scale} "
           f"edgefactor={args.edgefactor}")

@@ -16,6 +16,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 import repro.bfs as bfs
 from repro.core import csr as csr_mod
 from repro.core import rmat
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--edgefactor", type=int, default=16)
     ap.add_argument("--roots", type=int, default=8)
     args = ap.parse_args()
+    compile_cache.enable()
 
     print(f"== Graph500 RMAT: SCALE={args.scale} "
           f"edgefactor={args.edgefactor}")

@@ -42,9 +42,14 @@ Field → paper-knob map (the §-references are to the source paper):
   layer loop, §4.1 direction decision, termination — as ONE Pallas
   launch with state VMEM-resident across layers; requires
   `supports_persistent` and an algorithm in the format's
-  `persistent_algorithms`) or ``"materialized"`` (the legacy full-E
-  edge stream; the ablation baseline).  Auto: the affinity row, else
-  ``"fused_gather"``.
+  `persistent_algorithms`), ``"materialized"`` (the legacy full-E
+  edge stream; the ablation baseline) or ``"xla"`` (the expansion as
+  XLA ops over the whole edge stream, restoration as the Pallas
+  kernel).  Auto: the affinity row, else ``"fused_gather"`` — and on
+  a TPU backend only a pipeline in the format's `tpu_pipelines`
+  (today ``"xla"``: the compiler refuses the other expansion
+  kernels); an explicit refused pipeline there raises
+  `errors.KernelRefusedError`.
 * ``packed``          — §3.3.1's bitmap compression as the engine's
   native per-layer representation (SIMD compaction kernel, V/8 mask
   bytes per layer) vs the legacy dense-mask arm.  Auto: ``True``.
@@ -75,6 +80,7 @@ import dataclasses
 from typing import Any
 
 from repro.core import engine as _engine
+from repro.kernels import interpret_mode
 
 AUTO = "auto"
 
@@ -310,7 +316,39 @@ class TraversalSpec:
                         f"{self.algorithm!r}: the in-kernel layer "
                         f"loop has no plain-jnp scalar arm — use one "
                         f"of {allowed}, or pipeline='megakernel'")
+            if self.pipeline == "xla" and isinstance(depth, int) \
+                    and depth > 0:
+                raise ValueError(
+                    f"prefetch_depth={depth} is invalid with "
+                    f"pipeline='xla': the XLA expansion streams no "
+                    f"tiles to prefetch — use prefetch_depth=0 (or "
+                    f"'auto')")
+            if not interpret_mode():
+                self._check_tpu(fmt, fmt_label)
         return self
+
+    def _check_tpu(self, fmt, fmt_label: str) -> None:
+        """On a TPU backend, reject a pipeline (or the semiring
+        portfolio) whose kernels the compiler refuses, quoting the
+        refusal — `resolve` never picks one for ``auto``."""
+        from repro.errors import KernelRefusedError
+        from repro.kernels import TPU_REFUSALS
+        allowed = getattr(fmt, "tpu_pipelines", ())
+        refused = getattr(fmt, "pipeline_kernels", {})
+        if self.is_semiring:
+            what = f"algorithm={self.algorithm!r}"
+            kernels = refused.get("semiring", ())
+        elif self.pipeline == AUTO or self.pipeline in allowed:
+            return
+        else:
+            what = f"pipeline={self.pipeline!r}"
+            kernels = refused.get(self.pipeline, ())
+        why = "; ".join(f"{k}: {TPU_REFUSALS[k]}" for k in kernels)
+        raise KernelRefusedError(
+            f"{what} on the {fmt_label!r} format needs Pallas kernels "
+            f"the TPU compiler refuses ({why or 'no TPU lowering'}) — "
+            f"on a TPU the format compiles pipeline in {allowed} "
+            f"with a BFS algorithm (or 'auto')")
 
     # -- auto resolution (exactly once, at plan time) --------------------
     def resolve(self, graph) -> "TraversalSpec":
@@ -358,6 +396,12 @@ class TraversalSpec:
         pipeline = self.pipeline
         if pipeline == AUTO:
             pipeline = auto("pipeline", "fused_gather")
+            tpu = getattr(fmt, "tpu_pipelines", ())
+            if not interpret_mode() and not self.is_semiring and tpu \
+                    and pipeline not in tpu:
+                # the affinity rows time kernels a TPU does not
+                # compile: there the candidates are the declared ones
+                pipeline = tpu[0]
             if pipeline == "persistent":
                 allowed = getattr(fmt, "persistent_algorithms", ())
                 ok = getattr(fmt, "supports_persistent", False) \
@@ -416,7 +460,8 @@ class TraversalSpec:
         depth = self.prefetch_depth
         if depth == AUTO:
             depth = int(auto("prefetch_depth", 0))
-            if depth > 0 and not getattr(fmt, "supports_prefetch", True):
+            if depth > 0 and (pipeline == "xla" or not getattr(
+                    fmt, "supports_prefetch", True)):
                 depth = 0
             if depth > 0 and self.is_semiring:
                 # same story as the pipeline: no prefetch stream in

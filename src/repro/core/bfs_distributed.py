@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import bitmap as bm
 from repro.core import engine
 from repro.core.csr import Csr, round_up
@@ -202,11 +201,11 @@ def make_bfs_program(v_loc: int, n_vertices: int, n_devices: int,
         if merge == "packed":
             # packed-word exchange: discoveries cross chips as OR'd
             # uint32 bitmap words; parents stay local until the end.
-            frontier = compat.pcast_varying(frontier, axis_names)
-            visited = compat.pcast_varying(visited, axis_names)
+            frontier = jax.lax.pcast(frontier, axis_names, to="varying")
+            visited = jax.lax.pcast(visited, axis_names, to="varying")
             parent_acc = (jnp.full((v_cap,), inf, jnp.int32)
                           .at[root].set(root.astype(jnp.int32)))
-            parent_acc = compat.pcast_varying(parent_acc, axis_names)
+            parent_acc = jax.lax.pcast(parent_acc, axis_names, to="varying")
 
             def body(s):
                 frontier, visited, parent_acc, layer = s
@@ -237,8 +236,8 @@ def make_bfs_program(v_loc: int, n_vertices: int, n_devices: int,
         # The carried bitmaps become device-varying after the first
         # all_gather; mark the (replicated) initial values as varying
         # so the while_loop carry types match.
-        frontier = compat.pcast_varying(frontier, axis_names)
-        visited = compat.pcast_varying(visited, axis_names)
+        frontier = jax.lax.pcast(frontier, axis_names, to="varying")
+        visited = jax.lax.pcast(visited, axis_names, to="varying")
         in_range = (root >= base) & (root < base + v_loc)
         parent_l = jnp.full((v_loc,), inf, jnp.int32)
         parent_l = jnp.where(
@@ -291,8 +290,8 @@ def _run(mesh, axis_names, n_vertices, max_layers, merge, rows_sh,
     program = make_bfs_program(v_loc, n_vertices, n_devices, axis_names,
                                max_layers, merge=merge)
     p_out = P(axis_names) if merge == "owner" else P()
-    shard = compat.shard_map(
-        program, mesh,
+    shard = jax.shard_map(
+        program, mesh=mesh,
         in_specs=(P(axis_names), P(axis_names), P()),
         out_specs=(p_out, P()))
     return shard(rows_sh, colstarts_sh, root)

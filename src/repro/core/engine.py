@@ -36,6 +36,11 @@ map onto engine phases as follows:
   - ``materialized`` (legacy, kept for the ablation axis) — the
     apportionment machinery (`edge_stream`) writes a full-E ``(u, v,
     valid)`` stream to HBM which the kernel then re-reads.
+  - ``xla`` — the expansion as XLA gathers and a scatter-min over the
+    whole edge stream, restoration as the Pallas kernel
+    (`make_xla_steps`).  The TPU compiler refuses the expansion
+    kernels of the other pipelines, so on a TPU this is the one the
+    formats declare (``GraphFormat.tpu_pipelines``).
 
   The scalar (plain-jnp) layer keeps the materialized apportionment in
   both pipelines; the batched kernels add a leading root axis so many
@@ -95,7 +100,7 @@ import numpy as np
 
 from repro.core import bitmap as bm
 from repro.core.csr import Csr, init_visited, padding_premarked_visited
-from repro.kernels import ops
+from repro.kernels import interpret_mode, ops
 
 MODE_SCALAR = 0     # plain-jnp Algorithm 2/3 layer
 MODE_SIMD = 1       # §4 Pallas expansion kernel (top-down)
@@ -105,7 +110,7 @@ MODE_NAMES = {MODE_SCALAR: "topdown", MODE_SIMD: "topdown",
               MODE_BOTTOMUP: "bottomup"}
 
 PIPELINES = ("fused_gather", "materialized", "megakernel",
-             "persistent")
+             "persistent", "xla")
 
 
 def _record_degrade(site: str, reason: str, fallback: str,
@@ -345,14 +350,19 @@ def rowsweep_stream(colstarts, rows, active_words, n_vertices: int,
     owners live in LOCAL ids (< v_loc) but neighbors are GLOBAL.
     """
     nbr_limit = n_vertices if nbr_limit is None else nbr_limit
-    e_pad = rows.shape[0]
-    deg = colstarts[1:] - colstarts[:-1]
-    u = jnp.repeat(jnp.arange(n_vertices, dtype=jnp.int32), deg,
-                   total_repeat_length=e_pad)
-    # padding slots carry sentinel neighbors, so the v-test alone
-    # invalidates them regardless of the repeat's tail fill
+    u = edge_owners(colstarts, int(rows.shape[0]), n_vertices)
     valid = bm.test_bits(active_words, u) & (rows < nbr_limit)
     return u, rows, valid
+
+
+def edge_owners(colstarts, e_pad: int, n_vertices: int):
+    """(E_pad,) owner vertex of every CSR ``rows`` slot — the degree
+    expansion of ``colstarts``.  Padding slots carry sentinel
+    neighbors, so a neighbor test alone invalidates them regardless of
+    the repeat's tail fill."""
+    deg = colstarts[1:] - colstarts[:-1]
+    return jnp.repeat(jnp.arange(n_vertices, dtype=jnp.int32), deg,
+                      total_repeat_length=e_pad)
 
 
 def compact_worklist(active, n: int):
@@ -561,7 +571,7 @@ def _resolve_tile_csr(tile: int | None, e_pad: int, fmt=None) -> int:
     interpret-mode floor keeps the unrolled grid <=32 steps, same
     budget as `_auto_tile`.
     """
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     floor = max(128, e_pad // 32) if interpret else 128
     if tile is None:
         # auto tiles (table or env) never exceed the edge stream —
@@ -812,6 +822,69 @@ def _make_megakernel_step(colstarts, rows_t, n_vertices: int, tile: int,
     return step
 
 
+def make_xla_steps(src, dst, n_vertices: int, algorithm: str,
+                   tiles_per_root: int) -> dict:
+    """The per-layer steps of ``pipeline="xla"``: the expansion as XLA
+    ops over a flat (owner, neighbor) slot stream, the restoration as
+    the Pallas kernel.
+
+    The TPU compiler lowers none of the expansion kernels
+    (`kernels.TPU_REFUSALS`), so this is the pipeline the streamed
+    formats declare in ``tpu_pipelines``.  ``src``/``dst`` are shared
+    by every root and sentinel-padded (``dst == V`` on padding slots).
+    Top-down gates a slot on its owner being in the frontier and
+    discovers the unvisited neighbor; bottom-up gates on the owner
+    being unvisited and the neighbor in the frontier, and discovers
+    the owner.  Discoveries are a scatter-min of negative parent marks
+    (the smallest candidate parent wins, so the tree is
+    deterministic), which `ops.restore` repairs and packs into the
+    layer's output bitmap.  MODE_SCALAR runs the plain-jnp
+    `expand_candidates` body over the same top-down stream.  Every
+    layer sweeps the whole stream: ``tiles_per_root`` is its tile
+    count for the stats.  Roots run one after another (`lax.map`): a
+    vmapped sweep puts the root axis minor in its (E, B) gathers,
+    which the TPU pads from B to 128 lanes (34 GiB at Graph500 scale
+    20 with B = 8)."""
+    real = dst < n_vertices
+
+    def gate(frontier, visited, bottom_up: bool):
+        if bottom_up:
+            return dst, src, (real & ~bm.test_bits(visited, src)
+                              & bm.test_bits(frontier, dst))
+        return src, dst, (real & bm.test_bits(frontier, src)
+                          & ~bm.test_bits(visited, dst))
+
+    def aux(frontier, launches):
+        return StepAux(jnp.int32(frontier.shape[0] * tiles_per_root),
+                       jnp.int32(0), launches)
+
+    def kernel_step(bottom_up: bool):
+        def mark(frontier, visited, parent):
+            u, v, valid = gate(frontier, visited, bottom_up)
+            idx = jnp.where(valid, v, parent.shape[0])
+            return parent.at[idx].min(u - n_vertices, mode="drop")
+
+        def step(frontier, visited, parent):
+            with ops.count_launches() as c:
+                p_marked = jax.lax.map(lambda a: mark(*a),
+                                       (frontier, visited, parent))
+                parent, delta = ops.restore(p_marked,
+                                            n_vertices=n_vertices)
+            return delta, visited | delta, parent, aux(frontier, c.count)
+        return step
+
+    def scalar_step(frontier, visited, parent):
+        out, visited, parent = jax.lax.map(
+            lambda a: expand_candidates(*gate(a[0], a[1], False), *a,
+                                        n_vertices, algorithm),
+            (frontier, visited, parent))
+        return out, visited, parent, aux(frontier, 0)
+
+    return {MODE_SCALAR: scalar_step,
+            MODE_SIMD: kernel_step(bottom_up=False),
+            MODE_BOTTOMUP: kernel_step(bottom_up=True)}
+
+
 def _bottomup_stream(colstarts, rows, visited_words, n_vertices: int,
                      c_size: int, e_size: int):
     """Apportion the adjacency of *unvisited* vertices (one root) —
@@ -879,6 +952,10 @@ def _make_steps(colstarts, rows, n_vertices, v_pad, e_pad, algorithm,
                 tile, pipeline: str = "fused_gather",
                 packed: bool = True, prefetch_depth: int = 0):
     check_pipeline(pipeline)
+    if pipeline == "xla":
+        return make_xla_steps(edge_owners(colstarts, e_pad, n_vertices),
+                              rows, n_vertices, algorithm,
+                              -(-e_pad // tile))
     # the persistent pipeline's PER-LAYER steps (the serve tier's
     # layer_step tick) are the megakernel steps — whole-traversal
     # queries never reach here (they route through
@@ -1124,8 +1201,8 @@ def _traverse_impl(fmt, roots, spec) -> EngineResult:
 _UNSET = object()       # legacy-shim sentinel: "knob not passed"
 
 _KNOB_DEFAULTS = dict(policy=None, algorithm="simd", tile=None,
-                      max_layers=64, pipeline="fused_gather",
-                      packed=True, prefetch_depth=0)
+                      max_layers=64, pipeline=None, packed=True,
+                      prefetch_depth=0)
 
 
 def _spec_from_knobs(entry: str, spec, knobs: dict):
@@ -1154,14 +1231,18 @@ def _spec_from_knobs(entry: str, spec, knobs: dict):
 
 def make_spec(*, policy=None, algorithm: str = "simd",
               tile: int | None = None, max_layers: int = 64,
-              pipeline: str = "fused_gather", packed: bool = True,
+              pipeline: str | None = None, packed: bool = True,
               prefetch_depth: int = 0):
     """Build a `TraversalSpec` from legacy-style knob values — the ONE
     knob->spec constructor (``policy=None`` -> `TopDown()`,
-    ``tile=None`` -> the format's auto rule).  Shared by the deprecated
-    shims (via `_spec_from_knobs`) and the `run_bfs*` wrapper drivers,
-    so the legacy default mapping cannot drift between surfaces."""
+    ``tile=None`` -> the format's auto rule, ``pipeline=None`` ->
+    ``"fused_gather"``, or ``"auto"`` on a TPU, whose compiler refuses
+    that pipeline's kernels).  Shared by the deprecated shims (via
+    `_spec_from_knobs`) and the `run_bfs*` wrapper drivers, so the
+    legacy default mapping cannot drift between surfaces."""
     from repro.api.spec import TraversalSpec
+    if pipeline is None:
+        pipeline = "fused_gather" if interpret_mode() else "auto"
     return TraversalSpec(
         policy=policy if policy is not None else TopDown(),
         algorithm=algorithm,
@@ -1391,7 +1472,7 @@ def traverse_hostloop(csr: Csr, root: int, *, policy=None,
     Returns (state, stats, direction_log).
     """
     policy = policy if policy is not None else TopDown()
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     v_pad = csr.n_vertices_padded
     frontier = bm.set_bits_exact(bm.zeros(v_pad),
                                  jnp.asarray([root], jnp.int32))

@@ -12,6 +12,7 @@ derives from `ReproError`:
 
     ReproError
     ├── GraphValidationError   (also ValueError)   admission-time input
+    ├── KernelRefusedError     (also ValueError)   no TPU lowering
     ├── AdmissionRejected                          load-shed at submit
     │   └── QueueFullError                         bounded-queue overflow
     ├── DeadlineExceeded                           query budget expired
@@ -48,6 +49,18 @@ class GraphValidationError(ReproError, ValueError):
     than an error: non-monotone ``colstarts``, out-of-range neighbor
     ids, wrong dtypes, NaN-shaped geometry, roots outside ``[0, V)``.
     The message always names the violated invariant and the fix.
+    """
+
+
+class KernelRefusedError(ReproError, ValueError):
+    """A plan asked, on a TPU backend, for a pipeline whose Pallas
+    kernels the TPU compiler refuses.
+
+    Raised by `TraversalSpec.validate` at plan time, before anything
+    is traced; the message quotes the compiler's refusal
+    (`repro.kernels.TPU_REFUSALS`) and names the pipeline the format
+    does compile (``GraphFormat.tpu_pipelines``).  ``auto`` never
+    resolves to such a pipeline.
     """
 
 

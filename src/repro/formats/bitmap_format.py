@@ -39,6 +39,8 @@ from repro.formats.registry import register
 class BitmapCompressedFormat(GraphFormat):
     name = "bitmap"
     supports_prefetch = False    # dense word sweep: no edge stream
+    # the word sweep is plain XLA under every pipeline it builds
+    tpu_pipelines = ("fused_gather", "materialized", "xla")
     # the word sweep stores bits, not neighbor ids — there is no
     # per-edge candidate stream to relax a semiring over, so the
     # algorithm portfolio (ISSUE 10) is rejected by `spec.validate`

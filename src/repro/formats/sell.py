@@ -46,7 +46,7 @@ from repro.core.csr import Csr, from_edges as csr_from_edges, round_up
 from repro.core.rmat import EdgeList
 from repro.formats.base import Footprint, GraphFormat, nbytes
 from repro.formats.registry import register
-from repro.kernels import ops
+from repro.kernels import interpret_mode, ops
 from repro.kernels.sell_expand import SLICE_C, W_QUANT
 
 
@@ -73,6 +73,16 @@ class SellFormat(GraphFormat):
     # (kernels/sell_expand.py `sell_relax_batched`); see
     # GraphFormat.supported_semirings
     supported_semirings = ("sssp", "cc", "ksource_bfs")
+    # on a TPU only the XLA expansion over the flattened slabs
+    # compiles; see GraphFormat.tpu_pipelines
+    tpu_pipelines = ("xla",)
+    pipeline_kernels = {
+        "fused_gather": ("bfs_sell_expand_batched",),
+        "materialized": ("bfs_sell_expand_batched",),
+        "megakernel": ("bfs_sell_layer_fused_batched",),
+        "persistent": ("bfs_sell_traversal_fused",),
+        "semiring": ("bfs_sell_relax_batched",),
+    }
 
     DEFAULT_SIGMA = 8 * SLICE_C   # SlimSell's typical local-sort window
 
@@ -227,13 +237,18 @@ class SellFormat(GraphFormat):
         from repro.core import bitmap as bm
         from repro.core.engine import expand_candidates
         v = self._n_vertices
-        nbr = self.cols.reshape(-1)
-        src = jnp.broadcast_to(self.slab_rows[:, None, :],
-                               self.cols.shape).reshape(-1)
+        src, nbr = self._slot_pairs()
         in_front = bm.test_bits(frontier, src) & (src < v)
         valid = in_front & (nbr < v)
         return expand_candidates(src, nbr, valid, frontier, visited,
                                  parent, v, algorithm)
+
+    def _slot_pairs(self):
+        """The slabs flattened to one (owner row, neighbor) stream;
+        padding lanes carry the sentinel in both."""
+        src = jnp.broadcast_to(self.slab_rows[:, None, :],
+                               self.cols.shape).reshape(-1)
+        return src, self.cols.reshape(-1)
 
     def _plan_slab_steps(self, active_words, slabs_per_step: int,
                          n_steps: int):
@@ -300,6 +315,9 @@ class SellFormat(GraphFormat):
         prefetch_depth = spec.prefetch_depth
         v = self._n_vertices
         n_steps = -(-self.n_slabs // tile)
+        if spec.pipeline == "xla":
+            return engine.make_xla_steps(*self._slot_pairs(), v,
+                                         algorithm, n_steps)
         # the persistent pipeline's PER-LAYER steps (the serve tier's
         # tick path) are the megakernel steps — whole-traversal
         # queries bypass steps entirely via `persistent_run`
@@ -417,7 +435,7 @@ class SellFormat(GraphFormat):
         unrolls the grid at trace time, so clamp to <=32 steps there
         (the engine's `_auto_tile` rule, in slab units)."""
         n_slabs = self.n_slabs
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
         floor = max(1, -(-n_slabs // 32)) if interpret else 1
         if tile is None:
             return floor

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 
 DEFAULT_TILE = 4096
 
@@ -42,7 +42,7 @@ def popcount(words, *, tile: int = DEFAULT_TILE, interpret: bool = True):
         in_specs=[pl.BlockSpec((tile,), lambda t: (t,))],
         out_specs=pl.BlockSpec((1,), lambda t: (0,)),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="bitmap_popcount",

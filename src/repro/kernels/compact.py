@@ -43,7 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitmap import BITS_PER_WORD, word_bits
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 
 DEFAULT_TILE_WORDS = 256   # 256 words = 8192 bits per grid step
 
@@ -175,7 +175,7 @@ def frontier_compact(words, *, size: int, fill: int,
         functools.partial(_compact_kernel, tile_words, fill),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((size,), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # accumulating output => sequential grid on the core
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -212,7 +212,7 @@ def frontier_compact_batched(words, *, size: int, fill: int,
         functools.partial(_compact_batched_kernel, tile_words, fill),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_batch, size), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # accumulating output => sequential grid on the core
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

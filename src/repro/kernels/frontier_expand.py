@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.bitmap import WORD_MASK, WORD_SHIFT
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 
 DEFAULT_TILE = 1024  # 8 sublanes x 128 lanes of int32
 
@@ -163,7 +163,7 @@ def frontier_expand(nbr, cand, valid, frontier, visited, out_init, p_init,
         out_specs=[whole(n_words), whole(v_pad)],
         out_shape=[jax.ShapeDtypeStruct((n_words,), jnp.uint32),
                    jax.ShapeDtypeStruct((v_pad,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # accumulating outputs => sequential grid on the core
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -213,7 +213,7 @@ def frontier_expand_batched(nbr, cand, valid, frontier, visited,
         out_specs=[whole(n_words), whole(v_pad)],
         out_shape=[jax.ShapeDtypeStruct((n_batch, n_words), jnp.uint32),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="bfs_frontier_expand_batched",

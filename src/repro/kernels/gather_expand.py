@@ -62,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitmap import WORD_MASK, WORD_SHIFT
 from repro.kernels.frontier_expand import _expand_tile
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 
 DEFAULT_TILE = 1024  # 8 sublanes x 128 lanes of int32
 
@@ -302,7 +302,7 @@ def gather_expand(worklist, n_active, rows, colstarts, frontier,
     whole = lambda n: pl.BlockSpec((n,), lambda t, wl, na: (0,))
     if prefetch_depth > 0:
         depth = min(int(prefetch_depth), n_blocks)
-        rows_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        rows_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
         scratch = [pltpu.VMEM((depth + 1, tile), jnp.int32),
                    pltpu.SemaphoreType.DMA((depth + 1,))]
         kernel = functools.partial(_gather_dma_kernel, n_vertices, tile,
@@ -326,7 +326,7 @@ def gather_expand(worklist, n_active, rows, colstarts, frontier,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_words,), jnp.uint32),
                    jax.ShapeDtypeStruct((v_pad,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # accumulating outputs => sequential grid on the core
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -496,7 +496,7 @@ def gather_relax_batched(worklist, n_active, rows, colstarts, frontier,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_batch, v_pad), vals.dtype),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -539,7 +539,7 @@ def gather_expand_batched(worklist, n_active, rows, colstarts, frontier,
     whole = lambda n: pl.BlockSpec((1, n), lambda b, t, wl, na: (b, 0))
     if prefetch_depth > 0:
         depth = min(int(prefetch_depth), n_blocks)
-        rows_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        rows_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
         scratch = [pltpu.VMEM((depth + 1, tile), jnp.int32),
                    pltpu.SemaphoreType.DMA((depth + 1,))]
         kernel = functools.partial(_gather_dma_batched_kernel,
@@ -567,7 +567,7 @@ def gather_expand_batched(worklist, n_active, rows, colstarts, frontier,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_batch, n_words), jnp.uint32),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=semantics),
         interpret=interpret,
         name="bfs_gather_expand_batched",

@@ -53,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bitmap import BITS_PER_WORD, word_bits
 from repro.kernels.gather_expand import (DEFAULT_TILE, _dma_pipeline,
                                          _gather_tile)
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 
 
 def _plan_in_kernel(n_vertices: int, tile: int, n_blocks: int,
@@ -239,7 +239,7 @@ def layer_fused(rows, colstarts, frontier, visited, p_init, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(n_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                   whole(n_cs), whole(n_words), whole(n_words),
                   whole(v_pad)],
         out_specs=[whole(n_words), whole(v_pad), whole(1)],
@@ -255,7 +255,7 @@ def layer_fused(rows, colstarts, frontier, visited, p_init, *,
         out_shape=[jax.ShapeDtypeStruct((n_words,), jnp.uint32),
                    jax.ShapeDtypeStruct((v_pad,), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # scratch work-list + accumulating outputs => sequential
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -295,7 +295,7 @@ def layer_fused_batched(rows, colstarts, frontier, visited, p_init, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(n_batch, n_blocks),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                   flat(n_cs), whole(n_words), whole(n_words),
                   whole(v_pad)],
         out_specs=[whole(n_words), whole(v_pad),
@@ -312,7 +312,7 @@ def layer_fused_batched(rows, colstarts, frontier, visited, p_init, *,
         out_shape=[jax.ShapeDtypeStruct((n_batch, n_words), jnp.uint32),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32),
                    jax.ShapeDtypeStruct((n_batch,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="bfs_layer_fused_batched",

@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas BFS kernels.
 
-Selects interpret mode automatically (CPU containers validate the
-kernel bodies in Python; real TPUs compile them), pads edge streams to
+Selects interpret mode through `kernels.interpret_mode` (CPU hosts run
+the kernel bodies in the interpreter; a TPU compiles them), pads edge streams to
 tile multiples, and enforces the VMEM budget that makes the
 bitmap-in-VMEM design legal (DESIGN.md §2).
 """
@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bitmap import BITS_PER_WORD
+from repro.kernels import VMEM_BYTES, interpret_mode
 from repro.kernels import bitmap_kernels, frontier_expand as fe
 from repro.kernels import compact as ck
 from repro.kernels import gather_expand as ge
@@ -21,7 +22,6 @@ from repro.kernels import restoration as rest
 from repro.kernels import sell_expand as se
 from repro.kernels import traversal_fused as tf
 
-VMEM_BYTES = 16 * 1024 * 1024  # v5e VMEM per core
 _VMEM_HEADROOM = 0.75          # leave room for pipeline double-buffers
 
 
@@ -37,10 +37,6 @@ def budget_detail(name: str, budget_bytes: int) -> str:
     degrade log names the budget that failed in the same format."""
     return (f"{name} working set {budget_bytes / 2**20:.2f} MiB > "
             f"VMEM budget {vmem_limit_bytes() / 2**20:.1f} MiB")
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -98,7 +94,7 @@ def expand(nbr, cand, valid, frontier, visited, out_init, p_init, *,
            check_frontier: bool = False, interpret: bool | None = None):
     """Pad + run the frontier-expansion kernel (top-down or bottom-up)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     budget = fe.vmem_budget(visited.shape[0], p_init.shape[0], tile)
     if budget > VMEM_BYTES * _VMEM_HEADROOM:
         raise ValueError(
@@ -131,7 +127,7 @@ def expand_batched(nbr, cand, valid, frontier, visited, out_init, p_init,
     (the kernel pins one root's bitmaps/P at a time).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     budget = fe.vmem_budget(visited.shape[1], p_init.shape[1], tile)
     if budget > VMEM_BYTES * _VMEM_HEADROOM:
         raise ValueError(
@@ -178,7 +174,7 @@ def gather_expand(worklist, n_active, rows, colstarts, frontier,
     the O(E) copy this kernel exists to remove).  ``prefetch_depth``
     > 0 selects the manual double-buffered DMA input pipeline."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _gather_budget_check(visited.shape[0], p_init.shape[0],
                          colstarts.shape[0], tile, prefetch_depth,
                          rows.shape[0] // tile)
@@ -202,7 +198,7 @@ def gather_expand_batched(worklist, n_active, rows, colstarts, frontier,
     n_active/bitmaps/P carry (B, ...); the CSR arrays are shared.
     The VMEM budget is per-root."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _gather_budget_check(visited.shape[1], p_init.shape[1],
                          colstarts.shape[0], tile, prefetch_depth,
                          rows.shape[0] // tile)
@@ -226,7 +222,7 @@ def gather_relax_batched(worklist, n_active, rows, colstarts, frontier,
     resolve.  Per-root VMEM working set: frontier words + 2 value rows
     + the parent row + colstarts + the double-buffered rows tiles."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n_words, v_pad = frontier.shape[1], vals.shape[1]
     budget = 4 * (n_words + 3 * v_pad + colstarts.shape[0]) \
         + 2 * 4 * tile
@@ -286,7 +282,7 @@ def sell(cols, slab_rows, frontier, visited, out_init, p_init, *,
     input pipeline.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _sell_budget_check(visited.shape[0], p_init.shape[0],
                        slabs_per_step, prefetch_depth,
                        -(-cols.shape[0] // slabs_per_step))
@@ -320,7 +316,7 @@ def sell_batched(cols, slab_rows, frontier, visited, out_init, p_init,
     every root.  VMEM budget is per-root.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _sell_budget_check(visited.shape[1], p_init.shape[1],
                        slabs_per_step, prefetch_depth,
                        -(-cols.shape[0] // slabs_per_step))
@@ -350,7 +346,7 @@ def sell_relax_batched(cols, slab_rows, worklist, n_active, frontier,
     (kernels/sell_expand.py `sell_relax_batched`).  Pads the slab axis
     itself; the per-root work-list contract matches `sell_batched`."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n_words, v_pad = frontier.shape[1], vals.shape[1]
     slab = slabs_per_step * (se.W_QUANT + 1) * se.SLICE_C * 4
     budget = 4 * (n_words + 3 * v_pad) + 2 * slab
@@ -372,36 +368,32 @@ def sell_relax_batched(cols, slab_rows, worklist, n_active, frontier,
 @_scoped("bfs.restore")
 def restore(parent, *, n_vertices: int, tile: int = rest.DEFAULT_TILE,
             interpret: bool | None = None):
-    """Run the restoration kernel; tile auto-shrinks to divide V_pad.
+    """Run the restoration kernel over a (V_pad,) or batched
+    (B, V_pad) parent; the delta bitmap comes back as (W,) / (B, W).
 
-    Accepts a batched (B, V_pad) parent too: restoration is
-    tile-independent, so the batch flattens through the same kernel
-    (the tile divides V_pad, so no tile straddles two roots); the
-    delta bitmap comes back as (B, W).
+    Restoration is element-wise, so the batch flattens through one
+    launch, zero-padded up to a tile multiple (a zero is an unmarked
+    parent) and sliced back, so no V_pad is too odd for the tile.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _charge_launch()
-    v_pad = parent.shape[-1]
-    t = min(tile, v_pad)
-    while v_pad % t:
-        t //= 2
-    t = max(t, 32)
-    if parent.ndim == 2:
-        b = parent.shape[0]
-        p, delta = rest.restoration(parent.reshape(-1),
-                                    n_vertices=n_vertices, tile=t,
-                                    interpret=interpret)
-        return (p.reshape(b, v_pad),
-                delta.reshape(b, v_pad // BITS_PER_WORD))
-    return rest.restoration(parent, n_vertices=n_vertices, tile=t,
-                            interpret=interpret)
+    flat = parent.reshape(-1)
+    n = flat.shape[0]
+    pad = (-n) % tile
+    if pad:
+        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+    p, delta = rest.restoration(flat, n_vertices=n_vertices, tile=tile,
+                                interpret=interpret)
+    words = parent.shape[:-1] + (parent.shape[-1] // BITS_PER_WORD,)
+    return (p[:n].reshape(parent.shape),
+            delta[:n // BITS_PER_WORD].reshape(words))
 
 
 @_scoped("bfs.popcount")
 def popcount(words, *, interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _charge_launch()
     return bitmap_kernels.popcount(words, interpret=interpret)
 
@@ -431,7 +423,7 @@ def frontier_compact(words, *, size: int, fill: int,
     bitmap -> (dense vertex queue (size,), count).  The packed
     replacement for `bitmap.compact` + `bitmap.popcount`."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _charge_launch()
     return ck.frontier_compact(words, size=size, fill=fill,
                                interpret=interpret)
@@ -443,7 +435,7 @@ def frontier_compact_batched(words, *, size: int, fill: int,
     """Batched compaction: (B, W) packed bitmaps -> ((B, size)
     queues, (B,) counts) in one launch."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     _charge_launch()
     return ck.frontier_compact_batched(words, size=size, fill=fill,
                                        interpret=interpret)
@@ -487,7 +479,7 @@ def layer_fused(rows, colstarts, frontier, visited, p_init, *,
     ``rows`` must already be padded to a tile multiple at build.
     Returns (out, parent, n_active) with restoration APPLIED."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n_blocks = rows.shape[0] // tile
     budget = _megakernel_budget(visited.shape[0], p_init.shape[0],
                                 colstarts.shape[0], tile,
@@ -513,7 +505,7 @@ def layer_fused_batched(rows, colstarts, frontier, visited, p_init, *,
     """Batched (leading root-axis) whole-layer megakernel: one launch,
     B restored layers.  The VMEM budget is per-root."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n_blocks = rows.shape[0] // tile
     budget = _megakernel_budget(visited.shape[1], p_init.shape[1],
                                 colstarts.shape[0], tile,
@@ -569,7 +561,7 @@ def sell_layer_fused(cols, slab_rows, frontier, visited, p_init, *,
     itself.  Returns (out, parent, n_active) with restoration
     APPLIED."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     budget = sell_megakernel_budget(visited.shape[0], p_init.shape[0],
                                     cols.shape[0], slabs_per_step,
                                     prefetch_depth)
@@ -599,7 +591,7 @@ def sell_layer_fused_batched(cols, slab_rows, frontier, visited,
     """Batched (leading root-axis) whole-layer SELL megakernel: one
     launch, B restored layers.  The VMEM budget is per-root."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     budget = sell_megakernel_budget(visited.shape[1], p_init.shape[1],
                                     cols.shape[0], slabs_per_step,
                                     prefetch_depth)
@@ -685,7 +677,7 @@ def traversal_fused_batched(rows, colstarts, frontier, visited, p_init,
     engine's whole-traversal contract — and charges exactly ONE launch
     to the trace-time counter."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     n_blocks = rows.shape[0] // tile
     budget = persistent_budget(visited.shape[1], p_init.shape[1],
                                colstarts.shape[0], tile,
@@ -716,7 +708,7 @@ def sell_traversal_fused_batched(cols, slab_rows, deg, frontier,
     no colstarts for the in-kernel Table 1 counters).  Same contract
     and launch accounting as `traversal_fused_batched`."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     budget = sell_persistent_budget(visited.shape[1], p_init.shape[1],
                                     cols.shape[0], slabs_per_step,
                                     visited.shape[0], max_layers,

@@ -60,7 +60,7 @@ from repro.kernels.gather_expand import (P_UNSET, _dma_pipeline,
                                          _relax_scatter_parents,
                                          _relax_scatter_vals)
 from repro.kernels.layer_fused import _restore_in_kernel
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 
 SLICE_C = 128   # rows per slice = TPU vector lane count (csr.LANES)
 W_QUANT = 8     # columns per slab: 8x128 int32 = one aligned tile
@@ -299,7 +299,7 @@ def sell_expand(cols, slab_rows, worklist, n_active, frontier, visited,
     whole = lambda n: pl.BlockSpec((n,), lambda t, wl, na: (0,))
     if prefetch_depth > 0:
         depth = min(int(prefetch_depth), n_steps)
-        any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
         cols_spec, rows_spec = any_spec, any_spec
         scratch = [pltpu.VMEM((depth + 1, slabs_per_step, W_QUANT,
                                SLICE_C), jnp.int32),
@@ -330,7 +330,7 @@ def sell_expand(cols, slab_rows, worklist, n_active, frontier, visited,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_words,), jnp.uint32),
                    jax.ShapeDtypeStruct((v_pad,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # accumulating outputs => sequential grid on the core
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -370,7 +370,7 @@ def sell_expand_batched(cols, slab_rows, worklist, n_active, frontier,
     whole = lambda n: pl.BlockSpec((1, n), lambda b, t, wl, na: (b, 0))
     if prefetch_depth > 0:
         depth = min(int(prefetch_depth), n_steps)
-        any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
         cols_spec, rows_spec = any_spec, any_spec
         scratch = [pltpu.VMEM((depth + 1, slabs_per_step, W_QUANT,
                                SLICE_C), jnp.int32),
@@ -404,7 +404,7 @@ def sell_expand_batched(cols, slab_rows, worklist, n_active, frontier,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_batch, n_words), jnp.uint32),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=semantics),
         interpret=interpret,
         name="bfs_sell_expand_batched",
@@ -582,7 +582,7 @@ def sell_layer_fused(cols, slab_rows, frontier, visited, p_init, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(n_steps,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                   pl.BlockSpec((n_slabs, SLICE_C), lambda t: (0, 0)),
                   whole(n_words), whole(n_words), whole(v_pad)],
         out_specs=[whole(n_words), whole(v_pad), whole(1)],
@@ -599,7 +599,7 @@ def sell_layer_fused(cols, slab_rows, frontier, visited, p_init, *,
         out_shape=[jax.ShapeDtypeStruct((n_words,), jnp.uint32),
                    jax.ShapeDtypeStruct((v_pad,), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             # SMEM work-list + accumulating outputs => sequential grid
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -633,7 +633,7 @@ def sell_layer_fused_batched(cols, slab_rows, frontier, visited,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(n_batch, n_steps),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                   pl.BlockSpec((n_slabs, SLICE_C), lambda b, t: (0, 0)),
                   whole(n_words), whole(n_words), whole(v_pad)],
         out_specs=[whole(n_words), whole(v_pad),
@@ -651,7 +651,7 @@ def sell_layer_fused_batched(cols, slab_rows, frontier, visited,
         out_shape=[jax.ShapeDtypeStruct((n_batch, n_words), jnp.uint32),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32),
                    jax.ShapeDtypeStruct((n_batch,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="bfs_sell_layer_fused_batched",
@@ -769,7 +769,7 @@ def sell_relax_batched(cols, slab_rows, worklist, n_active, frontier,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_batch, v_pad), vals.dtype),
                    jax.ShapeDtypeStruct((n_batch, v_pad), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,

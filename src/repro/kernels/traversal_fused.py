@@ -60,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bitmap import WORD_MASK, WORD_SHIFT, word_bits
 from repro.kernels.gather_expand import DEFAULT_TILE, _owner_search
 from repro.kernels.layer_fused import _plan_in_kernel, _restore_in_kernel
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import compiler_params
 from repro.kernels.sell_expand import (SLICE_C, W_QUANT,
                                        _plan_slabs_in_kernel)
 
@@ -445,7 +445,7 @@ def traversal_fused_batched(rows, colstarts, frontier, visited, p_init,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                   whole(n_cs), whole(n_batch, n_words),
                   whole(n_batch, n_words), whole(n_batch, v_pad)],
         out_specs=[whole(n_batch, n_words), whole(n_batch, n_words),
@@ -464,7 +464,7 @@ def traversal_fused_batched(rows, colstarts, frontier, visited, p_init,
                    jax.ShapeDtypeStruct((n_batch,), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32),
                    jax.ShapeDtypeStruct((max_layers, _N_ST), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="bfs_traversal_fused",
@@ -505,7 +505,7 @@ def sell_traversal_fused_batched(cols, slab_rows, deg, frontier,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                   whole(n_slabs, SLICE_C), whole(n_deg),
                   whole(n_batch, n_words), whole(n_batch, n_words),
                   whole(n_batch, v_pad)],
@@ -527,7 +527,7 @@ def sell_traversal_fused_batched(cols, slab_rows, deg, frontier,
                    jax.ShapeDtypeStruct((n_batch,), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32),
                    jax.ShapeDtypeStruct((max_layers, _N_ST), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="bfs_sell_traversal_fused",

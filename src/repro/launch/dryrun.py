@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import registry
 from repro.configs.bfs_graph500 import GRAPHS
 from repro.launch import inputs
@@ -299,11 +298,11 @@ def lower_bfs_cell(graph_name: str, mesh_name: str,
     program_full = make_bfs_program(v_loc, g.n_vertices, n_chips, axes,
                                     merge=merge)
     p_out = P() if merge == "allreduce" else P(axes)
-    shard = compat.shard_map(
-        program, mesh,
+    shard = jax.shard_map(
+        program, mesh=mesh,
         in_specs=(P(axes), P(axes), P()), out_specs=(p_out, P()))
-    shard_full = compat.shard_map(
-        program_full, mesh,
+    shard_full = jax.shard_map(
+        program_full, mesh=mesh,
         in_specs=(P(axes), P(axes), P()), out_specs=(p_out, P()))
     rows_s = jax.ShapeDtypeStruct((n_chips, e_loc), jnp.int32)
     cs_s = jax.ShapeDtypeStruct((n_chips, v_loc + 1), jnp.int32)

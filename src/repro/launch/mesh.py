@@ -11,12 +11,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:                       # jax >= 0.5; absent on the 0.4.x line
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.models.sharding import DEFAULT_RULES, SINGLE_POD_RULES
 
@@ -28,8 +23,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -103,6 +103,8 @@ def test_distributed_eight_devices_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
+    # CPU only: a child never takes a chip the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _SUBPROC], env=env,
                          capture_output=True, text=True, timeout=900)
     assert "MULTIDEV_OK" in out.stdout, out.stderr[-3000:]
