@@ -260,7 +260,10 @@ class CompiledTraversal:
             axis_names = tuple(self.mesh.axis_names)
             n_devices = int(np.prod([self.mesh.shape[a]
                                      for a in axis_names]))
-            rows_sh, colstarts_sh = dist.partition_csr(csr, n_devices)
+            rows_sh, colstarts_sh = jax.device_put(
+                dist.partition_csr(csr, n_devices),
+                jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec(axis_names)))
             self._partition = (csr.n_vertices, axis_names, rows_sh,
                                colstarts_sh)
         n_vertices, axis_names, rows_sh, colstarts_sh = self._partition

@@ -43,9 +43,9 @@ Field → paper-knob map (the §-references are to the source paper):
   launch with state VMEM-resident across layers; requires
   `supports_persistent` and an algorithm in the format's
   `persistent_algorithms`), ``"materialized"`` (the legacy full-E
-  edge stream; the ablation baseline) or ``"xla"`` (the expansion as
-  XLA ops over the whole edge stream, restoration as the Pallas
-  kernel).  Auto: the affinity row, else ``"fused_gather"`` — and on
+  edge stream; the ablation baseline) or ``"xla"`` (the shared
+  `expand_candidates` body as XLA ops over the whole edge stream, no
+  Pallas kernel).  Auto: the affinity row, else ``"fused_gather"`` — and on
   a TPU backend only a pipeline in the format's `tpu_pipelines`
   (today ``"xla"``: the compiler refuses the other expansion
   kernels); an explicit refused pipeline there raises
@@ -334,16 +334,14 @@ class TraversalSpec:
         from repro.errors import KernelRefusedError
         from repro.kernels import TPU_REFUSALS
         allowed = getattr(fmt, "tpu_pipelines", ())
-        refused = getattr(fmt, "pipeline_kernels", {})
         if self.is_semiring:
-            what = f"algorithm={self.algorithm!r}"
-            kernels = refused.get("semiring", ())
+            what, key = f"algorithm={self.algorithm!r}", "semiring"
         elif self.pipeline == AUTO or self.pipeline in allowed:
             return
         else:
-            what = f"pipeline={self.pipeline!r}"
-            kernels = refused.get(self.pipeline, ())
-        why = "; ".join(f"{k}: {TPU_REFUSALS[k]}" for k in kernels)
+            what, key = f"pipeline={self.pipeline!r}", self.pipeline
+        why = "; ".join(f"{k}: {r}"
+                        for k, r in TPU_REFUSALS.get(key, {}).items())
         raise KernelRefusedError(
             f"{what} on the {fmt_label!r} format needs Pallas kernels "
             f"the TPU compiler refuses ({why or 'no TPU lowering'}) — "

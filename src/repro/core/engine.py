@@ -36,11 +36,11 @@ map onto engine phases as follows:
   - ``materialized`` (legacy, kept for the ablation axis) — the
     apportionment machinery (`edge_stream`) writes a full-E ``(u, v,
     valid)`` stream to HBM which the kernel then re-reads.
-  - ``xla`` — the expansion as XLA gathers and a scatter-min over the
-    whole edge stream, restoration as the Pallas kernel
-    (`make_xla_steps`).  The TPU compiler refuses the expansion
-    kernels of the other pipelines, so on a TPU this is the one the
-    formats declare (``GraphFormat.tpu_pipelines``).
+  - ``xla`` — the shared `expand_candidates` body as XLA ops over
+    the whole edge stream, no Pallas kernel (`make_xla_steps`).  The
+    TPU compiler refuses the expansion kernels of the other
+    pipelines, so on a TPU this is the one the formats declare
+    (``GraphFormat.tpu_pipelines``).
 
   The scalar (plain-jnp) layer keeps the materialized apportionment in
   both pipelines; the batched kernels add a leading root axis so many
@@ -824,65 +824,44 @@ def _make_megakernel_step(colstarts, rows_t, n_vertices: int, tile: int,
 
 def make_xla_steps(src, dst, n_vertices: int, algorithm: str,
                    tiles_per_root: int) -> dict:
-    """The per-layer steps of ``pipeline="xla"``: the expansion as XLA
-    ops over a flat (owner, neighbor) slot stream, the restoration as
-    the Pallas kernel.
+    """The per-layer steps of ``pipeline="xla"``: the shared
+    `expand_candidates` body, as XLA ops, over a flat (owner, neighbor)
+    slot stream.
 
     The TPU compiler lowers none of the expansion kernels
     (`kernels.TPU_REFUSALS`), so this is the pipeline the streamed
     formats declare in ``tpu_pipelines``.  ``src``/``dst`` are shared
     by every root and sentinel-padded (``dst == V`` on padding slots).
     Top-down gates a slot on its owner being in the frontier and
-    discovers the unvisited neighbor; bottom-up gates on the owner
-    being unvisited and the neighbor in the frontier, and discovers
-    the owner.  Discoveries are a scatter-min of negative parent marks
-    (the smallest candidate parent wins, so the tree is
-    deterministic), which `ops.restore` repairs and packs into the
-    layer's output bitmap.  MODE_SCALAR runs the plain-jnp
-    `expand_candidates` body over the same top-down stream.  Every
-    layer sweeps the whole stream: ``tiles_per_root`` is its tile
-    count for the stats.  Roots run one after another (`lax.map`): a
-    vmapped sweep puts the root axis minor in its (E, B) gathers,
-    which the TPU pads from B to 128 lanes (34 GiB at Graph500 scale
-    20 with B = 8)."""
+    discovers the neighbor; bottom-up swaps the stream, gating on the
+    neighbor being in the frontier and discovering the owner.  The
+    plain and SIMD top-down modes are one step.  Every layer sweeps
+    the whole stream: ``tiles_per_root`` is its tile count for the
+    stats.  Roots run one after another (`lax.map`): a vmapped sweep
+    puts the root axis minor in its (E, B) gathers, which the TPU pads
+    from B to 128 lanes (34 GiB at Graph500 scale 20 with B = 8)."""
     real = dst < n_vertices
 
-    def gate(frontier, visited, bottom_up: bool):
-        if bottom_up:
-            return dst, src, (real & ~bm.test_bits(visited, src)
-                              & bm.test_bits(frontier, dst))
-        return src, dst, (real & bm.test_bits(frontier, src)
-                          & ~bm.test_bits(visited, dst))
+    def make_step(bottom_up: bool):
+        u, v = (dst, src) if bottom_up else (src, dst)
 
-    def aux(frontier, launches):
-        return StepAux(jnp.int32(frontier.shape[0] * tiles_per_root),
-                       jnp.int32(0), launches)
-
-    def kernel_step(bottom_up: bool):
-        def mark(frontier, visited, parent):
-            u, v, valid = gate(frontier, visited, bottom_up)
-            idx = jnp.where(valid, v, parent.shape[0])
-            return parent.at[idx].min(u - n_vertices, mode="drop")
+        def one_root(state):
+            frontier, visited, parent = state
+            valid = real & bm.test_bits(frontier, u)
+            return expand_candidates(u, v, valid, frontier, visited,
+                                     parent, n_vertices, algorithm)
 
         def step(frontier, visited, parent):
-            with ops.count_launches() as c:
-                p_marked = jax.lax.map(lambda a: mark(*a),
-                                       (frontier, visited, parent))
-                parent, delta = ops.restore(p_marked,
-                                            n_vertices=n_vertices)
-            return delta, visited | delta, parent, aux(frontier, c.count)
+            out, visited, parent = jax.lax.map(
+                one_root, (frontier, visited, parent))
+            aux = StepAux(jnp.int32(frontier.shape[0] * tiles_per_root),
+                          jnp.int32(0), jnp.int32(0))
+            return out, visited, parent, aux
         return step
 
-    def scalar_step(frontier, visited, parent):
-        out, visited, parent = jax.lax.map(
-            lambda a: expand_candidates(*gate(a[0], a[1], False), *a,
-                                        n_vertices, algorithm),
-            (frontier, visited, parent))
-        return out, visited, parent, aux(frontier, 0)
-
-    return {MODE_SCALAR: scalar_step,
-            MODE_SIMD: kernel_step(bottom_up=False),
-            MODE_BOTTOMUP: kernel_step(bottom_up=True)}
+    top_down = make_step(bottom_up=False)
+    return {MODE_SCALAR: top_down, MODE_SIMD: top_down,
+            MODE_BOTTOMUP: make_step(bottom_up=True)}
 
 
 def _bottomup_stream(colstarts, rows, visited_words, n_vertices: int,

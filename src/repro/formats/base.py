@@ -138,13 +138,9 @@ class GraphFormat(abc.ABC):
     #: pipelines whose steps the TPU compiler accepts for this layout.
     #: On a TPU backend `TraversalSpec.resolve` picks ``auto`` from
     #: these only, and `spec.validate(fmt)` turns a request for any
-    #: other pipeline into a `KernelRefusedError` that quotes the
-    #: refusals of its `pipeline_kernels`
+    #: other pipeline into a `KernelRefusedError` that quotes its
+    #: refusals (`kernels.TPU_REFUSALS`)
     tpu_pipelines: ClassVar[tuple] = ()
-
-    #: pipeline (or ``"semiring"``) -> the Pallas kernels its steps
-    #: launch that the TPU compiler refuses (`kernels.TPU_REFUSALS`)
-    pipeline_kernels: ClassVar[dict] = {}
 
     # -- construction ----------------------------------------------------
     @classmethod

@@ -43,16 +43,6 @@ class CsrFormat(GraphFormat):
     # on a TPU only the XLA expansion compiles; see
     # GraphFormat.tpu_pipelines and tests/test_tpu_compile.py
     tpu_pipelines = ("xla",)
-    pipeline_kernels = {
-        "fused_gather": ("bfs_frontier_compact_batched",
-                         "bfs_gather_expand_batched"),
-        "materialized": ("bfs_frontier_compact_batched",
-                         "bfs_frontier_expand_batched"),
-        "megakernel": ("bfs_layer_fused_batched",),
-        "persistent": ("bfs_traversal_fused",),
-        "semiring": ("bfs_frontier_compact_batched",
-                     "bfs_gather_relax_batched"),
-    }
 
     def __init__(self, colstarts, rows, n_vertices: int, n_edges: int):
         self.colstarts = colstarts

@@ -76,13 +76,6 @@ class SellFormat(GraphFormat):
     # on a TPU only the XLA expansion over the flattened slabs
     # compiles; see GraphFormat.tpu_pipelines
     tpu_pipelines = ("xla",)
-    pipeline_kernels = {
-        "fused_gather": ("bfs_sell_expand_batched",),
-        "materialized": ("bfs_sell_expand_batched",),
-        "megakernel": ("bfs_sell_layer_fused_batched",),
-        "persistent": ("bfs_sell_traversal_fused",),
-        "semiring": ("bfs_sell_relax_batched",),
-    }
 
     DEFAULT_SIGMA = 8 * SLICE_C   # SlimSell's typical local-sort window
 

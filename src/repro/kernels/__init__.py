@@ -5,12 +5,11 @@ Mosaic through `compiler_params`, and the working-set predicates in
 `ops` test against the same number, so a kernel the predicates admit
 is never refused by the compiler for its VMEM use (and vice versa).
 
-`TPU_REFUSALS` records, per kernel, what the TPU compiler answers for
-the kernels it does not lower (tests/test_tpu_compile.py compiles
-each one for a described v5e and checks the record still holds).  The
-graph formats name the kernels each pipeline needs, and a request for
-a refused one on a TPU backend raises `errors.KernelRefusedError`
-quoting this table.
+`TPU_REFUSALS` records, per pipeline, the Pallas kernels its steps
+launch and what the TPU compiler answers for each
+(tests/test_tpu_compile.py compiles each one for a described v5e and
+checks the record still holds).  A request for such a pipeline on a
+TPU backend raises `errors.KernelRefusedError` quoting this table.
 """
 from __future__ import annotations
 
@@ -27,29 +26,31 @@ _BLOCK_SHAPE = ("The Pallas TPU lowering currently requires that the "
                 "last two dimensions of your block shape are divisible "
                 "by 8 and 128 respectively")
 _GATHER = "Only 2D gather is supported"
+_CUMSUM = ("Unimplemented primitive in Pallas TPU lowering for "
+           "KernelType.TC: cumsum")
+_SCATTER_ADD = ("Unimplemented primitive in Pallas TPU lowering for "
+                "KernelType.TC: scatter-add")
 
-#: Pallas kernel name -> the start of the TPU compiler's refusal (jax
-#: 0.9.0 / libtpu 0.0.34, v5e, Graph500 scale-20 widths).  The batched
-#: kernels' (1, W) row blocks are refused before their 1-D vector
-#: gathers are reached; the unbatched forms show the gather refusal.
+#: pipeline (``"semiring"``: the algorithm portfolio's relax steps) ->
+#: each Pallas kernel its steps launch on the csr and sell layouts ->
+#: the start of the TPU compiler's refusal (jax 0.9.0 / libtpu 0.0.34,
+#: v5e, Graph500 scale-20 widths).  The (1, W) row blocks of the
+#: batched kernels are refused before their 1-D vector gathers are
+#: reached.  A pipeline absent here launches no refused kernel.
 TPU_REFUSALS = {
-    "bfs_frontier_compact": "Unimplemented primitive in Pallas TPU "
-                            "lowering for KernelType.TC: cumsum",
-    "bfs_frontier_compact_batched": "Unimplemented primitive in Pallas "
-                                    "TPU lowering for KernelType.TC: "
-                                    "cumsum",
-    "bfs_frontier_expand": _GATHER,
-    "bfs_frontier_expand_batched": _BLOCK_SHAPE,
-    "bfs_gather_expand": _GATHER,
-    "bfs_gather_expand_batched": _BLOCK_SHAPE,
-    "bfs_gather_relax_batched": _BLOCK_SHAPE,
-    "bfs_layer_fused_batched": _BLOCK_SHAPE,
-    "bfs_traversal_fused": "Unimplemented primitive in Pallas TPU "
-                           "lowering for KernelType.TC: scatter-add",
-    "bfs_sell_expand_batched": _BLOCK_SHAPE,
-    "bfs_sell_layer_fused_batched": _BLOCK_SHAPE,
-    "bfs_sell_relax_batched": _BLOCK_SHAPE,
-    "bfs_sell_traversal_fused": _GATHER,
+    "fused_gather": {"bfs_frontier_compact_batched": _CUMSUM,
+                     "bfs_gather_expand_batched": _BLOCK_SHAPE,
+                     "bfs_sell_expand_batched": _BLOCK_SHAPE},
+    "materialized": {"bfs_frontier_compact_batched": _CUMSUM,
+                     "bfs_frontier_expand_batched": _BLOCK_SHAPE,
+                     "bfs_sell_expand_batched": _BLOCK_SHAPE},
+    "megakernel": {"bfs_layer_fused_batched": _BLOCK_SHAPE,
+                   "bfs_sell_layer_fused_batched": _BLOCK_SHAPE},
+    "persistent": {"bfs_traversal_fused": _SCATTER_ADD,
+                   "bfs_sell_traversal_fused": _GATHER},
+    "semiring": {"bfs_frontier_compact_batched": _CUMSUM,
+                 "bfs_gather_relax_batched": _BLOCK_SHAPE,
+                 "bfs_sell_relax_batched": _BLOCK_SHAPE},
 }
 
 

@@ -14,8 +14,8 @@ Three groups:
 * the steps of ``pipeline="xla"`` — the one the formats declare in
   ``tpu_pipelines`` — compile whole: the serve tick and the fused
   whole-search program;
-* every refusal recorded in `repro.kernels.TPU_REFUSALS` is still what
-  the compiler answers, so the declaration cannot go stale.
+* every kernel refusal recorded in `repro.kernels.TPU_REFUSALS` is
+  still what the compiler answers, so the declaration cannot go stale.
 
 The topology is described inside a fixture (never at import) and the
 tests skip where it cannot be described.  They trace with
@@ -33,7 +33,7 @@ from repro.core import engine
 from repro.core.csr import padded_vertex_count
 from repro.formats.csr_format import CsrFormat
 from repro.formats.sell import SellFormat
-from repro.kernels import (TPU_REFUSALS, bitmap_kernels, compact, ops,
+from repro.kernels import (TPU_REFUSALS, compact, ops,
                            frontier_expand as fe, gather_expand as ge,
                            layer_fused as lf, sell_expand as se,
                            traversal_fused as tf)
@@ -138,9 +138,7 @@ def test_csr_whole_search_compiles(sds, on_tpu):
     over both xla steps (the policy ``auto`` picks on RMAT)."""
     fmt = CsrFormat(sds((V + 1,)), sds((E,)), V, E)
     ex = _Executable(_spec(engine.BeamerHybrid()))
-    lowered = ex.run_jit.lower(fmt, sds((B,)))
-    assert "bfs_restoration" in lowered.as_text()
-    _fits_hbm(lowered.compile())
+    _fits_hbm(ex.run_jit.lower(fmt, sds((B,))).compile())
 
 
 def test_sell_serve_tick_compiles(sds, on_tpu):
@@ -151,9 +149,7 @@ def test_sell_serve_tick_compiles(sds, on_tpu):
                      SellFormat.DEFAULT_SIGMA, N_SLABS * se.W_QUANT
                      * se.SLICE_C)
     ex = _Executable(_spec(engine.TopDown()).replace(tile=1))
-    lowered = ex.layer_jit.lower(fmt, *_state(sds))
-    assert "bfs_restoration" in lowered.as_text()
-    _fits_hbm(lowered.compile())
+    _fits_hbm(ex.layer_jit.lower(fmt, *_state(sds)).compile())
 
 
 # ---------------------------------------------------------------------------
@@ -166,26 +162,16 @@ def _refused_calls(s):
     v, b, w = V, B, W
     kw = dict(n_vertices=v, interpret=False)
     rows, cs = s((E,)), s((v + 1,))
-    bits, bbits = s((w,), U32), s((b, w), U32)
-    par, bpar = s((V_PAD,)), s((b, V_PAD))
+    bbits, bpar = s((b, w), U32), s((b, V_PAD))
     cols = s((N_SLABS, se.W_QUANT, se.SLICE_C))
     slab_rows = s((N_SLABS, se.SLICE_C))
     return {
-        "bfs_frontier_compact": (
-            lambda x: compact.frontier_compact(
-                x, size=V_PAD, fill=v, interpret=False), (bits,)),
         "bfs_frontier_compact_batched": (
             lambda x: compact.frontier_compact_batched(
                 x, size=V_PAD // b, fill=v, interpret=False), (bbits,)),
-        "bfs_frontier_expand": (
-            lambda *a: fe.frontier_expand(*a, tile=1024, **kw),
-            (rows, rows, rows, bits, bits, bits, par)),
         "bfs_frontier_expand_batched": (
             lambda *a: fe.frontier_expand_batched(*a, tile=1024, **kw),
             (s((b, E // b)),) * 3 + (bbits, bbits, bbits, bpar)),
-        "bfs_gather_expand": (
-            lambda *a: ge.gather_expand(*a, tile=TILE, **kw),
-            (s((N_BLOCKS,)), s((1,)), rows, cs, bits, bits, bits, par)),
         "bfs_gather_expand_batched": (
             lambda *a: ge.gather_expand_batched(*a, tile=TILE, **kw),
             (s((b, N_BLOCKS)), s((b,)), rows, cs, bbits, bbits, bbits,
@@ -217,11 +203,15 @@ def _refused_calls(s):
     }
 
 
-@pytest.mark.parametrize("name", sorted(TPU_REFUSALS))
+REFUSED = {k: r for kernels in TPU_REFUSALS.values()
+           for k, r in kernels.items()}
+
+
+@pytest.mark.parametrize("name", sorted(REFUSED))
 def test_recorded_refusal_still_holds(sds, name):
     calls = _refused_calls(sds)
-    assert set(calls) == set(TPU_REFUSALS)
+    assert set(calls) == set(REFUSED)
     fn, args = calls[name]
     with pytest.raises(Exception) as err:
         _compile(fn, *args)
-    assert TPU_REFUSALS[name] in str(err.value)
+    assert REFUSED[name] in str(err.value)

@@ -83,9 +83,9 @@ def test_explicit_refused_pipeline_raises_typed_error(graphs, fmt_name,
     fmt = registry.get(fmt_name).from_graph(graphs["rmat9"])
     with pytest.raises(KernelRefusedError) as err:
         bfs.plan(fmt, bfs.TraversalSpec(pipeline=pipeline))
-    kernels = fmt.pipeline_kernels[pipeline]
-    assert kernels and all(TPU_REFUSALS[k] in str(err.value)
-                           for k in kernels)
+    refusals = TPU_REFUSALS[pipeline]
+    assert refusals and all(f"{k}: {r}" in str(err.value)
+                            for k, r in refusals.items())
 
 
 def test_semiring_on_tpu_raises_typed_error(graphs, on_tpu):
