@@ -1,10 +1,8 @@
 """Shared benchmark utilities: graph cache, timing, CSV emission.
 
-Every `emit` is also recorded in the in-process ``RESULTS`` registry
-AND mirrored into the `repro.obs` metrics registry (gauge
-``bench.<name>``), so one metrics snapshot shows benchmark TEPS/bytes
-next to the serve-tier distributions; `benchmarks.run` persists the
-registry to ``BENCH_bfs.json`` at the repo root after each run
+Every `emit` is also recorded in the in-process ``RESULTS`` registry;
+`benchmarks.run` persists that registry to ``BENCH_bfs.json`` at the
+repo root after each run
 (merge-update, so partial ``--only`` runs refresh just their keys) —
 the cross-PR perf trajectory file the CI bytes-moved gate reads.
 Since ISSUE 7 the file also carries a ``_meta`` record (git sha,
@@ -58,19 +56,12 @@ def emit(name: str, us_per_call: float, derived: str,
     ``value`` optionally attaches a machine-readable number (TEPS,
     analytic bytes, tile counts) to the ``RESULTS``/BENCH_bfs.json
     record — what regression gates compare instead of parsing the
-    derived string.  Every emit is mirrored into the process metrics
-    registry as gauges ``bench.<name>`` (the value, when given) and
-    ``bench.<name>.us_per_call``."""
+    derived string."""
     print(f"{name},{us_per_call:.1f},{derived}")
     rec = {"us_per_call": round(us_per_call, 1), "derived": derived}
     if value is not None:
         rec["value"] = float(value)
     RESULTS[name] = rec
-    from repro.obs import get_registry
-    reg = get_registry()
-    reg.gauge(f"bench.{name}.us_per_call").set(us_per_call)
-    if value is not None:
-        reg.gauge(f"bench.{name}").set(float(value))
 
 
 def build_meta(timestamp: str | None = None) -> dict:

@@ -12,7 +12,10 @@ model still matches what XLA actually compiles.
 
 Three modules, one concern each:
 
-* `obs.trace`      — span tracer (traversal → layer → step nesting,
+* `obs.trace`      — `span`, the one span primitive (a
+  ``jax.profiler.TraceAnnotation``: on the device trace's clock while
+  a profiler session is open, about a microsecond otherwise), the
+  span tracer (traversal → layer → step nesting,
   wall clock + optional device sync) exporting Chrome trace-event
   JSON viewable in Perfetto, plus the host-stepped instrumented
   traversal (`trace_run`) that reuses the plan cache's compiled
@@ -20,9 +23,9 @@ Three modules, one concern each:
   fast path.
 * `obs.metrics`    — process-local counters/gauges/histograms with a
   JSON snapshot and Prometheus-style text exposition; the serve tier
-  records submit→harvest latency (p50/p99), tick duration, queue
-  depth and slot occupancy through it, and every benchmark `emit`
-  lands here too.
+  records submit→harvest latency (p50/p99) and its queue-wait /
+  in-slot parts, tick duration, queue depth and slot occupancy
+  through it.
 * `obs.cost_drift` — the analytic `layer_bytes`/`traversal_bytes`
   models compared against what the compiled program reports
   (``jax.jit(...).lower().compile().cost_analysis()`` and the
@@ -35,7 +38,8 @@ from repro.obs.metrics import (Counter, DegradeEvent, Gauge, Histogram,
                                MetricsRegistry, clear_degrade_log,
                                degrade_log, get_registry,
                                record_degrade)
-from repro.obs.trace import SpanTracer, TraceRun, trace_run, xla_profiler
+from repro.obs.trace import (SpanTracer, TraceRun, span, trace_run,
+                             xla_profiler)
 
 __all__ = [
     "Counter",
@@ -52,6 +56,7 @@ __all__ = [
     "get_registry",
     "measure_drift",
     "record_degrade",
+    "span",
     "trace_run",
     "xla_profiler",
 ]

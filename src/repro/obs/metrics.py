@@ -1,9 +1,8 @@
 """Process-local metrics registry: counters, gauges, histograms.
 
 The serve tier's operational truth lives here — per-query
-submit→harvest latency, tick duration, queue depth, slot occupancy —
-and every benchmark ``emit`` mirrors its value in, so one snapshot
-shows TEPS/bytes next to the serving distributions they explain.
+submit→harvest latency and its queue-wait / in-slot split, tick
+duration, queue depth, slot occupancy.
 
 Deliberately dependency-free and synchronous (this is a single-process
 engine; the registry is the in-process end of the pipe a real
@@ -225,8 +224,8 @@ class MetricsRegistry:
         return "\n".join(lines) + ("\n" if lines else "")
 
 
-#: the process-default registry — what the serve tier and benchmark
-#: `emit` record into unless handed an explicit one
+#: the process-default registry — what the serve tier records into
+#: unless handed an explicit one
 _REGISTRY = MetricsRegistry()
 
 

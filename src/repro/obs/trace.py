@@ -5,10 +5,17 @@ synchronization inside the layer loop — which is exactly why nothing
 can time its layers.  This module adds the *time* axis without
 touching that fast path:
 
+* `span` — the one span primitive: a ``jax.profiler.TraceAnnotation``.
+  With a profiler session open it lands on the host plane of the same
+  ``.xplane.pb`` as the device ops, on the same clock, its keyword
+  args as event stats; with none it costs about a microsecond, so
+  the serve tick (`serve.graph_engine`) keeps its spans on always.
 * `SpanTracer` — a context-manager span recorder (nesting:
   traversal → layer → step) that exports Chrome trace-event JSON;
   open ``chrome://tracing`` or https://ui.perfetto.dev and load the
-  file.  Spans are wall-clock (``time.perf_counter``); callers pass
+  file.  Each of its spans also enters `span`, so it shows on a
+  profiler timeline too.  Its own times are wall-clock
+  (``time.perf_counter``); callers pass
   device arrays to `SpanTracer.device_sync` so a span's close waits
   for the device work it timed (otherwise JAX's async dispatch would
   attribute everything to the first sync).
@@ -61,6 +68,15 @@ PERSISTENT_SPAN = "bfs.traversal.persistent"
 SEMIRING_SPAN = "bfs.traversal.semiring"
 
 
+def span(name: str, **args: Any) -> jax.profiler.TraceAnnotation:
+    """A span on the profiler's clock: ``with span("serve.tick",
+    tick=3) as sp: ...``.  ``args`` (numbers or strings) become the
+    event's stats; ``sp.set_metadata(**more)`` adds stats known only
+    inside the block.  Recorded only while a ``jax.profiler`` trace is
+    open."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 @dataclass
 class Span:
     """One closed span: microsecond offset + duration relative to the
@@ -104,14 +120,15 @@ class SpanTracer:
         kwargs become the trace event's ``args`` and may be amended on
         the yielded `Span` before exit."""
         s = Span(name, args=dict(args))
-        self._stack.append(s)
-        s.ts_us = self._now_us()
-        try:
-            yield s
-        finally:
-            s.dur_us = self._now_us() - s.ts_us
-            self._stack.pop()
-            self.spans.append(s)
+        with span(name, **args):
+            self._stack.append(s)
+            s.ts_us = self._now_us()
+            try:
+                yield s
+            finally:
+                s.dur_us = self._now_us() - s.ts_us
+                self._stack.pop()
+                self.spans.append(s)
 
     def device_sync(self, *arrays) -> None:
         """Wait for device work (``jax.block_until_ready``) so the

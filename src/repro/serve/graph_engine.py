@@ -40,6 +40,27 @@ of delivered; and the ``serve.circuit_state`` gauge exports the
 healthy/degraded/shedding breaker position.  Chaos coverage drives a
 `serve.robust.ServeFaultInjector` through all of it
 (``make chaos-smoke``).
+
+**Spans** (`obs.trace.span`, on the profiler's clock; recorded
+whenever a ``jax.profiler`` trace is open, about a microsecond each
+otherwise).  Every `GraphEngine.step` is one ``serve.tick`` (args
+``tick``, ``active``) holding, in order:
+
+* ``serve.fill`` — queue expiry and slot refill (the root transfers
+  and ``_reset_slot`` dispatches); arg ``refilled``;
+* ``serve.dispatch`` — the enqueue of ``layer_step``, retries
+  included; arg ``attempts``;
+* ``serve.readback`` — the frontier popcount readback, which waits
+  for the tick's device work;
+* ``serve.harvest`` — one per delivered or re-queued slot (sanity
+  check and result copy); args ``uid``, ``layers``.
+
+A tick with no active slot holds only ``serve.fill``.  Each query's
+``meta`` carries its lifecycle on the ``time.perf_counter`` clock:
+``submit_t`` (arrival; set by `submit` unless the caller set it),
+``slot_t`` (the last time `step` placed it in a slot) and
+``harvest_t`` (delivery), read by the ``serve.queue_wait_s`` and
+``serve.in_slot_s`` histograms.
 """
 from __future__ import annotations
 
@@ -56,6 +77,7 @@ from repro.core import engine
 from repro.errors import (AdmissionRejected, DeadlineExceeded,
                           QueueFullError, TickRetriesExhausted)
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import span
 from repro.serve import robust
 
 
@@ -96,6 +118,11 @@ class BfsQuery:
     retries: int = 0                   # times this query was re-run
     #                                    (tick failure / poisoned slot)
     meta: dict = field(default_factory=dict)
+    #  lifecycle stamps (``time.perf_counter`` seconds): a caller may
+    #  set ``meta["submit_t"]`` to the query's arrival time before
+    #  `GraphEngine.submit`, which otherwise stamps it; the engine
+    #  stamps ``slot_t`` at each slot fill and ``harvest_t`` on
+    #  delivery
 
 
 class GraphEngine:
@@ -126,7 +153,10 @@ class GraphEngine:
         metrics into (default: the process registry,
         `repro.obs.get_registry()`).  Recorded under ``serve.*``:
         per-query submit→harvest latency (``serve.query_latency_s``
-        histogram — p50/p99 in its snapshot), tick duration
+        histogram — p50/p99 in its snapshot; from arrival where the
+        caller set ``meta["submit_t"]``) and its two parts,
+        submit→slot (``serve.queue_wait_s``) and slot→harvest
+        (``serve.in_slot_s``), tick duration
         (``serve.tick_s``), queue depth / slot occupancy /
         circuit-state gauges, and tick/query/skip/reject/retry
         counters.
@@ -239,6 +269,12 @@ class GraphEngine:
         self._m_latency = self.metrics.histogram(
             "serve.query_latency_s",
             "submit->harvest wall seconds per query")
+        self._m_queue_wait = self.metrics.histogram(
+            "serve.queue_wait_s",
+            "submit->slot wall seconds per query (its last slot fill)")
+        self._m_in_slot = self.metrics.histogram(
+            "serve.in_slot_s",
+            "slot->harvest wall seconds per query (its last slot fill)")
         self._m_tick = self.metrics.histogram(
             "serve.tick_s", "wall seconds per engine tick")
         self._m_queue = self.metrics.gauge(
@@ -396,16 +432,21 @@ class GraphEngine:
             self._m_deadline.inc()
         self._m_queue.set(len(self.queue))
 
-    def _fill_slots(self):
+    def _fill_slots(self) -> int:
+        """Place queued queries in free slots; returns how many."""
+        refilled = 0
         for i, q in enumerate(self.slots):
             if (q is None or q.done) and self.queue:
                 nxt = self.queue.pop()
+                nxt.meta["slot_t"] = time.perf_counter()
                 self.slots[i] = nxt
                 self.frontier, self.visited, self.parent = _reset_slot(
                     self.frontier, self.visited, self.parent,
                     self._base_visited, jnp.asarray(nxt.root, jnp.int32),
                     slot=i, n_vertices=self.n_vertices)
+                refilled += 1
         self._m_queue.set(len(self.queue))
+        return refilled
 
     def _active_slots(self) -> int:
         return sum(q is not None and not q.done for q in self.slots)
@@ -442,9 +483,10 @@ class GraphEngine:
             if q is not None and not q.done:
                 self._requeue(i, q)
 
-    def _dispatch_with_retry(self, tick_no: int) -> None:
+    def _dispatch_with_retry(self, tick_no: int) -> int:
         """Run the device tick, retrying with capped exponential
-        backoff.  `CompiledTraversal.layer_step` is functional (new
+        backoff; returns the attempts it took.
+        `CompiledTraversal.layer_step` is functional (new
         arrays out; assignment only on success), so a failed attempt
         cannot corrupt slot state.  On exhaustion every in-flight
         query is re-queued (restart from root) and
@@ -461,7 +503,7 @@ class GraphEngine:
                 self.frontier, self.visited, self.parent = \
                     self.compiled.layer_step(
                         self.frontier, self.visited, self.parent)
-                return
+                return attempt + 1
             except Exception as exc:    # noqa: BLE001 — retry any
                 last = exc              # device-step failure flavour
                 self._m_retries.inc()
@@ -495,10 +537,14 @@ class GraphEngine:
             self._m_truncated.inc()
         if isinstance(error, DeadlineExceeded):
             self._m_deadline.inc()
+        now = q.meta["harvest_t"] = time.perf_counter()
+        t_slot = q.meta["slot_t"]
+        self._m_in_slot.observe(now - t_slot)
         t0 = q.meta.get("submit_t")
         if t0 is not None:
-            q.meta["latency_s"] = time.perf_counter() - t0
+            q.meta["latency_s"] = now - t0
             self._m_latency.observe(q.meta["latency_s"])
+            self._m_queue_wait.observe(t_slot - t0)
         return True
 
     def run_direct(self, roots) -> engine.EngineResult:
@@ -579,10 +625,13 @@ class GraphEngine:
         dispatched — the tick is a host no-op counted in
         ``serve.ticks_skipped``.  Before ISSUE 7 every such tick paid
         a full compiled step for zero active queries."""
-        with self._m_tick.time():
-            self._expire_queued()
-            self._fill_slots()
+        with self._m_tick.time(), \
+                span("serve.tick", tick=self._tick_no) as tick_span:
+            with span("serve.fill") as fill_span:
+                self._expire_queued()
+                fill_span.set_metadata(refilled=self._fill_slots())
             n_active = self._active_slots()
+            tick_span.set_metadata(active=n_active)
             self._m_occupancy.set(n_active / max(len(self.slots), 1))
             self._set_circuit_gauge()
             if n_active == 0:
@@ -591,7 +640,9 @@ class GraphEngine:
             self._m_ticks.inc()
             tick_no = self._tick_no
             self._tick_no += 1
-            self._dispatch_with_retry(tick_no)
+            with span("serve.dispatch") as dispatch_span:
+                dispatch_span.set_metadata(
+                    attempts=self._dispatch_with_retry(tick_no))
             if self.injector is not None:
                 for s in self.injector.poison_slots(tick_no):
                     if 0 <= s < len(self.slots) \
@@ -604,7 +655,8 @@ class GraphEngine:
                         self.parent = self.parent.at[s].set(
                             (jnp.arange(v_pad, dtype=jnp.int32) + 1)
                             % self.n_vertices)
-            counts = np.asarray(engine.row_popcounts(self.frontier))
+            with span("serve.readback"):
+                counts = np.asarray(engine.row_popcounts(self.frontier))
             now = time.perf_counter()
             for i, q in enumerate(self.slots):
                 if q is None or q.done:
@@ -614,20 +666,22 @@ class GraphEngine:
                           else self.max_layers)
                 elapsed = now - q.meta.get("submit_t", now)
                 if counts[i] == 0:
-                    self._harvest(i, q)
+                    truncated, error = False, None
                 elif q.deadline_s is not None \
                         and elapsed > q.deadline_s:
-                    self._harvest(
-                        i, q, truncated=True,
-                        error=DeadlineExceeded(
-                            f"query uid={q.uid} exceeded its "
-                            f"deadline_s={q.deadline_s} after "
-                            f"{elapsed:.3f}s / {q.n_layers} layers "
-                            f"(partial tree delivered)",
-                            uid=q.uid, elapsed_s=elapsed,
-                            budget_s=q.deadline_s, where="in_flight"))
+                    truncated, error = True, DeadlineExceeded(
+                        f"query uid={q.uid} exceeded its "
+                        f"deadline_s={q.deadline_s} after "
+                        f"{elapsed:.3f}s / {q.n_layers} layers "
+                        f"(partial tree delivered)",
+                        uid=q.uid, elapsed_s=elapsed,
+                        budget_s=q.deadline_s, where="in_flight")
                 elif q.n_layers >= budget:
-                    self._harvest(i, q, truncated=True)
+                    truncated, error = True, None
+                else:
+                    continue
+                with span("serve.harvest", uid=q.uid, layers=q.n_layers):
+                    self._harvest(i, q, truncated=truncated, error=error)
 
     def _harvest_global_budget(self, budget_s: float,
                                elapsed: float) -> None:

@@ -1,5 +1,8 @@
 """Unit tests for the obs subsystem (PR 7): span tracer, metrics
-registry, instrumented trace_run, and the cost-drift model probe."""
+registry, instrumented trace_run, the serve tick's profiler spans, and
+the cost-drift model probe."""
+import contextlib
+import glob
 import json
 import math
 
@@ -17,6 +20,11 @@ from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry,
 from repro.obs.cost_drift import analytic_layer_bytes
 from repro.obs.trace import (LAYER_SPAN, STEP_SPAN, TRAVERSAL_SPAN,
                              xla_profiler)
+from repro.serve.graph_engine import BfsQuery, GraphEngine
+
+#: the serve tick's phase spans, each inside a ``serve.tick``
+PHASES = ("serve.fill", "serve.dispatch", "serve.readback",
+          "serve.harvest")
 
 
 @pytest.fixture(scope="module")
@@ -192,6 +200,84 @@ def test_trace_run_reuses_plan_and_tracer(g8):
     tracer = SpanTracer()
     tr = ct.trace_run(0, tracer=tracer)
     assert tr.tracer is tracer and len(tracer) > 0
+
+
+def _host_events(logdir, prefixes):
+    """``(name, start_ns, end_ns, stats)`` of the host-plane events in
+    the one profile written under ``logdir`` whose names start with
+    one of ``prefixes``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith(prefixes)]
+
+
+def test_span_tracer_spans_reach_the_profiler(g8, tmp_path):
+    tr = trace_run(g8, 0, profile_logdir=str(tmp_path))
+    names = [e[0] for e in _host_events(tmp_path, ("bfs.",))]
+    assert names.count(TRAVERSAL_SPAN) == 1
+    assert names.count(LAYER_SPAN) == len(tr.stats)
+    assert names.count(STEP_SPAN) == len(tr.stats)
+
+
+def _serve(g, traced_dir=None):
+    """Ten queries over four slots, then one tick with nothing to do;
+    under a profiler trace into ``traced_dir`` when given."""
+    reg = MetricsRegistry()
+    eng = GraphEngine(g, batch_slots=4, registry=reg)
+    for uid in range(10):
+        eng.submit(BfsQuery(uid=uid, root=(uid * 37) % g.n_vertices))
+    with (jax.profiler.trace(traced_dir) if traced_dir
+          else contextlib.nullcontext()):
+        eng.run_until_done()
+        eng.step()
+    return eng, reg
+
+
+@pytest.fixture(scope="module")
+def traced_serve(g8, tmp_path_factory):
+    logdir = str(tmp_path_factory.mktemp("serve_trace"))
+    eng, reg = _serve(g8, logdir)
+    return eng, reg, _host_events(logdir, ("serve.",))
+
+
+def test_serve_tick_spans_on_the_profiler_timeline(traced_serve):
+    eng, reg, events = traced_serve
+    ticks = [e for e in events if e[0] == "serve.tick"]
+    counters = reg.snapshot()["counters"]
+    assert len(ticks) == counters["serve.ticks"] \
+        + counters["serve.ticks_skipped"]
+    assert counters["serve.ticks_skipped"] >= 1
+    harvests = [e for e in events if e[0] == "serve.harvest"]
+    assert len(harvests) == len(eng.finished) == 10
+    assert sorted(e[3]["uid"] for e in harvests) \
+        == sorted(q.uid for q in eng.finished)
+    layers = {q.uid: q.n_layers for q in eng.finished}
+    assert all(e[3]["layers"] == layers[e[3]["uid"]] for e in harvests)
+    phases = [e for e in events if e[0] in PHASES]
+    assert {e[0] for e in phases} == set(PHASES)
+    for name, start, end, _ in phases:
+        assert any(t[1] <= start and end <= t[2] for t in ticks), name
+    dispatched = [t for t in ticks if t[3]["active"] > 0]
+    assert len(dispatched) == counters["serve.ticks"]
+    assert sum(e[0] == "serve.dispatch" for e in events) \
+        == counters["serve.ticks"]
+    assert sum(e[3].get("refilled", 0) for e in events
+               if e[0] == "serve.fill") == 10
+
+
+def test_serve_results_identical_with_spans_recorded(g8, traced_serve):
+    traced, _, _ = traced_serve
+    plain, _ = _serve(g8)
+    by_uid = {q.uid: q for q in plain.finished}
+    assert len(by_uid) == len(traced.finished) == 10
+    for q in traced.finished:
+        other = by_uid[q.uid]
+        assert q.n_layers == other.n_layers
+        np.testing.assert_array_equal(q.parent, other.parent)
 
 
 # -- cost drift -----------------------------------------------------------

@@ -344,6 +344,60 @@ def test_vmem_fallback_degrade_is_observable():
     clear_degrade_log()
 
 
+# -- query lifecycle --------------------------------------------------------
+def _histogram(reg, name):
+    return reg.snapshot()["histograms"][name]
+
+
+def test_caller_arrival_time_and_the_latency_split():
+    reg = MetricsRegistry()
+    eng = _engine(registry=reg, batch_slots=1)
+    arrival = time.perf_counter() - 5.0
+    late = BfsQuery(uid=0, root=0, meta={"submit_t": arrival})
+    queued = BfsQuery(uid=1, root=1)
+    eng.submit(late)
+    eng.submit(queued)
+    eng.run_until_done()
+    # the caller's arrival time is kept: latency runs from it
+    assert late.meta["submit_t"] == arrival
+    assert late.meta["latency_s"] >= 5.0
+    assert _histogram(reg, "serve.query_latency_s")["max"] >= 5.0
+    for q in (late, queued):
+        m = q.meta
+        assert m["submit_t"] <= m["slot_t"] <= m["harvest_t"]
+        assert (m["slot_t"] - m["submit_t"]) + (m["harvest_t"]
+                                                - m["slot_t"]) \
+            == pytest.approx(m["latency_s"], abs=1e-9)
+    # the second query waited for the one slot
+    assert queued.meta["slot_t"] >= late.meta["harvest_t"]
+    waits = _histogram(reg, "serve.queue_wait_s")
+    in_slot = _histogram(reg, "serve.in_slot_s")
+    latency = _histogram(reg, "serve.query_latency_s")
+    assert waits["count"] == in_slot["count"] == latency["count"] == 2
+    assert waits["sum"] + in_slot["sum"] \
+        == pytest.approx(latency["sum"], abs=1e-9)
+
+
+def test_requeued_query_gets_a_new_slot_time():
+    reg = MetricsRegistry()
+    inj = robust.ServeFaultInjector(poison=((0, 0),))
+    eng = _engine(registry=reg, batch_slots=1, injector=inj)
+    q = BfsQuery(uid=0, root=0)
+    eng.submit(q)
+    eng.step()
+    first = q.meta["slot_t"]
+    eng.run_until_done()
+    assert q.retries == 1 and validate(CSR, q.parent, q.root).ok
+    assert q.meta["slot_t"] > first
+    # only the delivered run is observed, from its own slot fill
+    in_slot = _histogram(reg, "serve.in_slot_s")
+    assert in_slot["count"] == 1
+    assert in_slot["sum"] == pytest.approx(
+        q.meta["harvest_t"] - q.meta["slot_t"], abs=1e-9)
+    assert _histogram(reg, "serve.queue_wait_s")["sum"] == pytest.approx(
+        q.meta["slot_t"] - q.meta["submit_t"], abs=1e-9)
+
+
 def test_finished_queries_are_exactly_once():
     """No duplicate delivery under mixed injection."""
     inj = robust.ServeFaultInjector(fail_ticks=(1,), poison=((0, 1),))
