@@ -6,8 +6,9 @@ Two scalar expansion flavours survive as the ``algorithm`` switch:
 * ``nonsimd`` — Algorithm 2 semantics.  Dense bool arrays for
   in/out/visited: no bit race exists because every vertex owns a whole
   element; only the *benign* parent race of §3.2 remains.
-* ``simd``    — Algorithm 3.  Bitmap arrays + the racy word scatter of
-  the hot loop + the **restoration process** (§3.3.2).  No atomics
+* ``simd``    — Algorithm 3.  Bitmap arrays + the racy parent-mark
+  scatter of the hot loop + the **restoration process** (§3.3.2),
+  which packs the new frontier from those marks.  No atomics
   anywhere — what made the paper's AVX-512 vectorization legal, and
   equally what makes the XLA/TPU scatter formulation legal.
 

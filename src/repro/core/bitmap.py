@@ -14,8 +14,11 @@ All helpers are pure-jnp, shape-static and jittable.  Two flavours of
 * ``set_bits_racy``     — gather-word / OR / scatter-word.  Duplicate
   word indices inside one call lose each other's updates ("some lane
   wins"), which is precisely the paper's *bit race condition* (§3.3.2,
-  Fig. 6).  Used by the vectorized expansion hot loop, exactly as the
-  paper uses non-atomic AVX-512 scatters.
+  Fig. 6), as with the paper's non-atomic AVX-512 scatters.  The jnp
+  expansion body writes no racy bitmap (it packs the new frontier from
+  the parent marks, `engine.expand_candidates`); the root init of
+  `engine.traverse_hostloop` and `bfs_parallel` and the race tests
+  use it.
 """
 from __future__ import annotations
 

@@ -37,10 +37,11 @@ map onto engine phases as follows:
     apportionment machinery (`edge_stream`) writes a full-E ``(u, v,
     valid)`` stream to HBM which the kernel then re-reads.
   - ``xla`` — the shared `expand_candidates` body as XLA ops over
-    the whole edge stream, no Pallas kernel (`make_xla_steps`).  The
-    TPU compiler refuses the expansion kernels of the other
-    pipelines, so on a TPU this is the one the formats declare
-    (``GraphFormat.tpu_pipelines``).
+    the whole edge stream, no Pallas kernel (`make_xla_steps`): per
+    slot and layer two word gathers (the owner gate, the visited
+    test) and one parent scatter.  The TPU compiler refuses the
+    expansion kernels of the other pipelines, so on a TPU this is the
+    one the formats declare (``GraphFormat.tpu_pipelines``).
 
   The scalar (plain-jnp) layer keeps the materialized apportionment in
   both pipelines; the batched kernels add a leading root axis so many
@@ -48,6 +49,9 @@ map onto engine phases as follows:
 * **restore** (§3.3.2, Alg. 3 lines 15-29): every vertex discovered
   this layer is identified by its negative ``P`` entry and its bit is
   re-set exactly — what makes the non-atomic vectorization legal.
+  The Pallas kernels repair their racy bitmap from these marks
+  (`ops.restore`); the jnp body (`expand_candidates`) writes no racy
+  bitmap at all and packs the new frontier from the marks alone.
 
 Two drivers expose the pipeline:
 
@@ -500,15 +504,6 @@ def candidate_scatter(u, v, valid, visited, n_vertices: int, v_cap: int):
     return cand.at[idx].min(u, mode="drop")
 
 
-def restore_jnp(parent, out, visited, n_vertices: int):
-    """Pure-jnp restoration (§3.3.2): repair racy bitmap drops from the
-    negative P marks.  Returns (parent, out, visited) all fixed."""
-    marked = parent < 0
-    repaired = bm.pack_bool(marked)
-    return (jnp.where(marked, parent + n_vertices, parent),
-            out | repaired, visited | repaired)
-
-
 @jax.jit
 def row_popcounts(words):
     """Set-bit count over the trailing word axis: (B, W) -> (B,) or
@@ -602,9 +597,16 @@ def expand_candidates(u, v, valid, frontier, visited, parent,
     ``vals[u] ⊗ w`` candidate with ⊕ (= min, commutative: no race, no
     restoration), then resolve min-id parents against the finalized
     values.  Returns ``(improved_words, new_vals, parent)`` — the
-    frontier-generation triple of `algorithms.traversal`.  With
-    ``semiring=None`` (the BFS default) the bit test-and-set paths
-    below run byte-identically to every release since ISSUE 1.
+    frontier-generation triple of `algorithms.traversal`.
+
+    Algorithm 3 (``"simd"``) reads one visited word per slot and
+    scatters ``u - V`` into ``P`` where the neighbor is undiscovered;
+    the negative marks then give the new frontier (packed), the new
+    visited bitmap (``visited | out``) and the restored ``P``.  So per
+    slot: one gather, one scatter, beside the caller's gate.  The body
+    relies on ``frontier ⊆ visited`` on entry, so it does not test the
+    frontier again: `init_root_state` sets the root in both bitmaps,
+    and every step returns an ``out`` inside its ``visited``.
     """
     v_pad = parent.shape[0]
     if semiring is not None:
@@ -632,15 +634,14 @@ def expand_candidates(u, v, valid, frontier, visited, parent,
                      .at[idx].set(True, mode="drop"))
         out = bm.pack_bool(out_dense)
         return out, visited | out, parent
-    # Algorithm 3: racy bitmap scatter + restoration
-    undiscovered = ~(bm.test_bits(visited, v)
-                     | bm.test_bits(frontier, v))
-    mask = valid & undiscovered
+    # Algorithm 3: negative P marks, then restoration from them alone
+    mask = valid & ~bm.test_bits(visited, v)
     idx = jnp.where(mask, v, v_pad)
     parent = parent.at[idx].set(u - n_vertices, mode="drop")
-    out = bm.set_bits_racy(bm.zeros(v_pad), v, mask)
-    parent, out, visited = restore_jnp(parent, out, visited, n_vertices)
-    return out, visited, parent
+    marked = parent < 0
+    out = bm.pack_bool(marked)
+    return out, visited | out, jnp.where(marked, parent + n_vertices,
+                                         parent)
 
 
 def scalar_expand(colstarts, rows, n_vertices: int, frontier, visited,
@@ -834,7 +835,11 @@ def make_xla_steps(src, dst, n_vertices: int, algorithm: str,
     by every root and sentinel-padded (``dst == V`` on padding slots).
     Top-down gates a slot on its owner being in the frontier and
     discovers the neighbor; bottom-up swaps the stream, gating on the
-    neighbor being in the frontier and discovering the owner.  The
+    neighbor being in the frontier and discovering the owner.  Per
+    slot the step gathers two words (the gate's frontier word, the
+    discovered side's visited word) and scatters at most one parent
+    mark: 2 gathers and 1 scatter over the stream, relying on
+    ``frontier ⊆ visited`` on entry (`expand_candidates`).  The
     plain and SIMD top-down modes are one step.  Every layer sweeps
     the whole stream: ``tiles_per_root`` is its tile count for the
     stats.  Roots run one after another (`lax.map`): a vmapped sweep

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import bitmap as bm
 from repro.core import csr as csr_mod
 from repro.core import engine, rmat
 from repro.core.bfs_parallel import parents_graph500
@@ -168,6 +169,53 @@ def test_hybrid_policy_switches_on_rmat(graphs):
     assert log[0] == "topdown" and "bottomup" in log
     check_oracle(g, np.asarray(parents_graph500(res.state,
                                                 g.n_vertices)), 17)
+
+
+def _racy_then_restore(u, v, valid, frontier, visited, parent, n):
+    """The Algorithm-3 body as it stood before the jnp path dropped its
+    racy bitmap: frontier re-test, racy word scatter, restoration."""
+    v_pad = parent.shape[0]
+    mask = valid & ~(bm.test_bits(visited, v) | bm.test_bits(frontier, v))
+    idx = jnp.where(mask, v, v_pad)
+    parent = parent.at[idx].set(u - n, mode="drop")
+    out = bm.set_bits_racy(bm.zeros(v_pad), v, mask)
+    marked = parent < 0
+    repaired = bm.pack_bool(marked)
+    return (out | repaired, visited | repaired,
+            jnp.where(marked, parent + n, parent))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("bottom_up", [False, True],
+                         ids=["topdown", "bottomup"])
+@pytest.mark.parametrize("graph_name", ["rmat10", "star"])
+def test_simd_body_matches_racy_restore_sequence(graphs, graph_name,
+                                                 bottom_up, seed):
+    """`expand_candidates(..., "simd")` is bit-identical to the racy
+    scatter + restoration sequence on states with frontier ⊆ visited,
+    over the xla steps' full-sweep stream in either direction."""
+    g = graphs[graph_name]
+    n, v_pad = g.n_vertices, g.n_vertices_padded
+    owners = engine.edge_owners(g.colstarts, g.n_edges_padded, n)
+    u, v = (g.rows, owners) if bottom_up else (owners, g.rows)
+    rng = np.random.default_rng(seed)
+    seen = rng.random(n) < rng.uniform(0.05, 0.6)
+    front = seen & (rng.random(n) < 0.5)
+    frontier = bm.set_bits_exact(
+        bm.zeros(v_pad), jnp.asarray(np.flatnonzero(front), jnp.int32))
+    visited = bm.set_bits_exact(
+        csr_mod.init_visited(g),
+        jnp.asarray(np.flatnonzero(seen), jnp.int32))
+    parent = np.full(v_pad, n, np.int32)
+    parent[np.flatnonzero(seen)] = rng.integers(0, n, int(seen.sum()))
+    parent = jnp.asarray(parent)
+    valid = (g.rows < n) & bm.test_bits(frontier, u)
+    got = engine.expand_candidates(u, v, valid, frontier, visited,
+                                   parent, n, "simd")
+    want = _racy_then_restore(u, v, valid, frontier, visited, parent, n)
+    assert int(bm.popcount(got[0])) > 0
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_serve_engine_drains_queue(graphs):

@@ -13,7 +13,8 @@ Three groups:
   interpreted: the lowered program holds a ``tpu_custom_call``);
 * the steps of ``pipeline="xla"`` — the one the formats declare in
   ``tpu_pipelines`` — compile whole: the serve tick and the fused
-  whole-search program;
+  whole-search program, each step with two gathers and one scatter
+  over the slot stream in the compiled program;
 * every kernel refusal recorded in `repro.kernels.TPU_REFUSALS` is
   still what the compiler answers, so the declaration cannot go stale.
 
@@ -22,6 +23,9 @@ tests skip where it cannot be described.  They trace with
 ``jax.default_backend`` reporting "tpu", as the program does on a chip.
 """
 from __future__ import annotations
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -133,23 +137,44 @@ def _fits_hbm(compiled):
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
 
 
+def _slot_stream_ops(hlo: str, n_slots: int):
+    """(gathers, scatters) over the slot stream inside the loops of a
+    compiled module: a gather that yields one value per slot, a
+    scatter indexed by one per slot."""
+    size = {m[1]: math.prod(int(d) for d in m[2].split(",") if d)
+            for m in re.finditer(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo)}
+    ops = {"gather": 0, "scatter": 0}
+    for line in hlo.splitlines():
+        m = re.search(r"%([\w.-]+) = \S+ (gather|scatter)\(%[\w.-]+, "
+                      r"%([\w.-]+)", line)
+        if m and "/while/body/" in line:
+            n = size[m[1]] if m[2] == "gather" else size[m[3]]
+            ops[m[2]] += n == n_slots
+    return ops["gather"], ops["scatter"]
+
+
 def test_csr_whole_search_compiles(sds, on_tpu):
     """`CompiledTraversal.run_batched`'s program: the Beamer layer loop
     over both xla steps (the policy ``auto`` picks on RMAT)."""
     fmt = CsrFormat(sds((V + 1,)), sds((E,)), V, E)
     ex = _Executable(_spec(engine.BeamerHybrid()))
-    _fits_hbm(ex.run_jit.lower(fmt, sds((B,))).compile())
+    compiled = ex.run_jit.lower(fmt, sds((B,))).compile()
+    _fits_hbm(compiled)
+    # two steps (top-down, bottom-up), each 2 gathers + 1 scatter
+    assert _slot_stream_ops(compiled.as_text(), E) == (4, 2)
 
 
 def test_sell_serve_tick_compiles(sds, on_tpu):
     """`GraphEngine`'s tick (`CompiledTraversal.layer_step`) on the
     layout ``graph_format="auto"`` builds for RMAT."""
+    slots = N_SLABS * se.W_QUANT * se.SLICE_C
     fmt = SellFormat(sds((N_SLABS, se.W_QUANT, se.SLICE_C)),
                      sds((N_SLABS, se.SLICE_C)), sds((V,)), V, E,
-                     SellFormat.DEFAULT_SIGMA, N_SLABS * se.W_QUANT
-                     * se.SLICE_C)
+                     SellFormat.DEFAULT_SIGMA, slots)
     ex = _Executable(_spec(engine.TopDown()).replace(tile=1))
-    _fits_hbm(ex.layer_jit.lower(fmt, *_state(sds)).compile())
+    compiled = ex.layer_jit.lower(fmt, *_state(sds)).compile()
+    _fits_hbm(compiled)
+    assert _slot_stream_ops(compiled.as_text(), slots) == (2, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -9,16 +9,19 @@ from __future__ import annotations
 
 import os
 import pathlib
+import re
 import shutil
 import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.bfs as bfs
 from repro import compile_cache
+from repro.core import bitmap as bm
 from repro.core import csr as csr_mod
 from repro.core import engine, rmat
 from repro.core.bfs_serial import bfs_serial
@@ -120,6 +123,51 @@ def test_xla_pipeline_rejects_prefetch(graphs):
     with pytest.raises(ValueError, match="pipeline='xla'"):
         bfs.plan(graphs["rmat9"], bfs.TraversalSpec(pipeline="xla",
                                                     prefetch_depth=1))
+
+
+@pytest.mark.parametrize("mode", [engine.MODE_SIMD, engine.MODE_BOTTOMUP],
+                         ids=["topdown", "bottomup"])
+def test_xla_step_sweeps_two_gathers_one_scatter(graphs, mode):
+    """Per slot and layer an xla step reads two words and writes at
+    most one parent: a pass brought back over the stream fails here."""
+    g = graphs["rmat9"]
+    e, w = g.n_edges_padded, g.n_vertices_padded // bm.BITS_PER_WORD
+    step = engine.make_xla_steps(
+        engine.edge_owners(g.colstarts, e, g.n_vertices), g.rows,
+        g.n_vertices, "simd", 1)[mode]
+    bits = jax.ShapeDtypeStruct((2, w), jnp.uint32)
+    text = jax.jit(step).lower(
+        bits, bits, jax.ShapeDtypeStruct((2, g.n_vertices_padded),
+                                         jnp.int32)).as_text()
+    gathers = re.findall(r'"stablehlo\.gather"\(.*: \(tensor<\w+>, '
+                         r'tensor<(\d+)x1xi32>\)', text)
+    assert gathers == [str(e)] * 2
+    assert text.count('"stablehlo.scatter"(') == 1
+
+
+@pytest.mark.parametrize("fmt_name", ("csr", "sell"))
+def test_xla_beamer_layers_keep_frontier_inside_visited(graphs, fmt_name):
+    """The invariant the xla body relies on, layer by layer: the
+    planned Beamer search's own directions replayed through its steps
+    on the host keep ``frontier & ~visited == 0`` and end on the
+    whole-search program's state."""
+    g = graphs["rmat9"]
+    ct = bfs.plan(registry.get(fmt_name).from_graph(g),
+                  bfs.TraversalSpec(pipeline="xla", policy="beamer"))
+    roots = jnp.asarray([1, 2, 3, 5], jnp.int32)
+    res = ct.run_batched(roots)
+    log = engine.direction_log(res)
+    assert {"topdown", "bottomup"} <= set(log)
+    steps = ct.fmt.make_steps(ct.resolved)
+    state = engine._init_batched(roots, g.n_vertices,
+                                 g.n_vertices_padded)
+    for name in log:
+        mode = (engine.MODE_BOTTOMUP if name == "bottomup"
+                else engine.MODE_SIMD)
+        state = steps[mode](*state)[:3]
+        assert not np.any(np.asarray(state[0] & ~state[1]))
+    for got, want in zip(state, res.state[:3]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def _run_smoke(cwd, script):
