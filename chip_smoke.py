@@ -199,7 +199,7 @@ def four_chips() -> None:
         _log(f"mesh root {root}: valid, depths equal the one-chip plan; "
              f"{int(layers)} layers; {wall:.2f} s")
     # the plan's edge partition: each chip must hold its own slice
-    _, _, rows_sh, _ = on_mesh._partition
+    rows_sh = on_mesh._partition.rows
     local = {s.device.id: int((np.asarray(s.data) < g.n_vertices).sum())
              for s in rows_sh.addressable_shards}
     if (sorted(local) != sorted(d.id for d in mesh.devices.flat)

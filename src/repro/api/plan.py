@@ -156,17 +156,24 @@ class CompiledTraversal:
         self.executable = executable      # None iff mesh-bound
         self.batch = batch
         self.mesh = mesh
-        self._partition = None            # mesh path: built once, lazily
+        # mesh path: the CSR partitioned over the mesh, built once here
+        self._partition = (None if mesh is None
+                           else _mesh_partition(fmt, mesh))
 
     # -- execution -------------------------------------------------------
     def run(self, roots) -> _engine.EngineResult:
         """Run for one root (int — unbatched result arrays) or a
         sequence of roots (leading root axis), `engine.traverse`
         semantics.  On a mesh-bound plan, runs the distributed program
-        instead and returns its ``(parent, layers)`` pair."""
+        for one scalar root instead and returns its ``(parent,
+        layers)`` pair."""
         if self.mesh is not None:
             check_roots(roots, self.fmt.n_vertices)
-            return self._run_distributed(roots)
+            if jnp.ndim(roots) != 0:
+                raise ValueError("the distributed program runs one root "
+                                 "per launch; pass a scalar root")
+            res = self._search(roots)
+            return res.state.parent[0, :self.fmt.n_vertices], res.depths[0]
         single = jnp.ndim(roots) == 0
         res = self.run_batched(
             jnp.atleast_1d(jnp.asarray(roots, jnp.int32)))
@@ -186,11 +193,16 @@ class CompiledTraversal:
         the same trace.  NB the ``stats`` buffer is summed over the
         *padded* batch on device (the duplicate roots' work included)
         — for exact Table 1 accounting use an exact-width plan
-        (``batch=None``)."""
+        (``batch=None``).
+
+        On a mesh-bound plan each root runs in a launch of its own
+        (`bfs_distributed.run`) and the results are stacked: parents
+        (B, v_cap), ``depths`` the layers each search ran, no stats.
+        The layer counts are read back to count the exchange's wire
+        bytes (``bfs.mesh.exchange_bytes``), so the call returns when
+        the searches have ended."""
         if self.mesh is not None:
-            raise NotImplementedError(
-                "mesh-bound plans run one root per launch via .run(); "
-                "batched multi-root distributed search is not wired up")
+            return self._run_batched_distributed(roots)
         check_roots(roots, self.fmt.n_vertices)
         roots = jnp.atleast_1d(jnp.asarray(roots, jnp.int32))
         n = int(roots.shape[0])
@@ -242,36 +254,37 @@ class CompiledTraversal:
         return _trace_run(self, roots, tracer=tracer, sync=sync,
                           profile_logdir=profile_logdir)
 
-    def _run_distributed(self, root):
+    def _search(self, root) -> _engine.EngineResult:
         from repro.core import bfs_distributed as dist
-        if jnp.ndim(root) != 0:
-            raise ValueError("the distributed program runs one root "
-                             "per launch; pass a scalar root")
-        if self._partition is None:
-            to_csr = getattr(self.fmt, "to_csr", None)
-            if to_csr is None:
-                raise TypeError(
-                    f"mesh-bound plans need a CSR-recoverable format; "
-                    f"{type(self.fmt).__name__} has no to_csr()")
-            # partition ONCE at first run — the host-side O(E) split
-            # is the mesh path's "compile" step; subsequent roots
-            # reuse the sharded arrays (plan-once/run-many)
-            csr = to_csr()
-            axis_names = tuple(self.mesh.axis_names)
-            n_devices = int(np.prod([self.mesh.shape[a]
-                                     for a in axis_names]))
-            rows_sh, colstarts_sh = jax.device_put(
-                dist.partition_csr(csr, n_devices),
-                jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec(axis_names)))
-            self._partition = (csr.n_vertices, axis_names, rows_sh,
-                               colstarts_sh)
-        n_vertices, axis_names, rows_sh, colstarts_sh = self._partition
-        parent, layers = dist._run(
-            self.mesh, axis_names, n_vertices,
-            self.resolved.max_layers, self.resolved.merge, rows_sh,
-            colstarts_sh, jnp.asarray(root, jnp.int32))
-        return parent[:n_vertices], layers
+        return dist.run(self._partition, root, self.resolved.max_layers,
+                        self.resolved.merge)
+
+    def _run_batched_distributed(self, roots) -> _engine.EngineResult:
+        from repro.core import bfs_distributed as dist
+        from repro.obs.metrics import get_registry
+        check_roots(roots, self.fmt.n_vertices)
+        roots = np.atleast_1d(np.asarray(roots))
+        if roots.shape[0] == 0:
+            raise ValueError("run_batched needs at least one root")
+        results = [self._search(r) for r in roots]
+        part = self._partition
+        get_registry().counter(
+            "bfs.mesh.exchange_bytes",
+            "bytes the mesh searches put on the wire").inc(sum(
+                dist.exchange_bytes(self.resolved.merge, part.v_cap,
+                                    part.n_devices,
+                                    int(np.asarray(r.depths)[0]))
+                for r in results))
+        if len(results) == 1:
+            return results[0]
+        st = [r.state for r in results]
+        return _engine.EngineResult(
+            _engine.BfsState(
+                jnp.concatenate([s.frontier for s in st]),
+                jnp.concatenate([s.visited for s in st]),
+                jnp.concatenate([s.parent for s in st]),
+                jnp.max(jnp.stack([s.layer for s in st]))),
+            jnp.concatenate([r.depths for r in results]), None)
 
     # -- introspection ---------------------------------------------------
     @property
@@ -283,11 +296,17 @@ class CompiledTraversal:
     def lower(self, roots=None):
         """``jax.jit(...).lower`` of the whole-search program — the
         dry-run/AOT hook.  ``roots`` defaults to a zero batch of the
-        plan's ``batch`` width (or 1)."""
+        plan's ``batch`` width (or 1).  On a mesh-bound plan it lowers
+        the one-root ``shard_map`` program every root of ``run`` and
+        ``run_batched`` launches."""
         if self.mesh is not None:
-            raise NotImplementedError(
-                "mesh-bound plans lower through launch/dryrun.py's "
-                "shard_map path, not the single-chip executable")
+            from repro.core import bfs_distributed as dist
+            root = 0 if roots is None else np.atleast_1d(
+                np.asarray(roots))[0]
+            check_roots(root, self.fmt.n_vertices)
+            return dist.lower(self._partition, root,
+                              self.resolved.max_layers,
+                              self.resolved.merge)
         if roots is None:
             roots = jnp.zeros((self.batch or 1,), jnp.int32)
         roots = jnp.atleast_1d(jnp.asarray(roots, jnp.int32))
@@ -319,9 +338,12 @@ def plan(graph, spec: TraversalSpec | None = None, *,
       batch: optional fixed batch width — `run_batched` pads smaller
         root batches up to it so varying query counts reuse one trace
         (the serving shape).
-      mesh: optional jax mesh — ``run`` then executes the distributed
-        per-chip program derived from the same resolved spec
-        (``merge``/``max_layers``).
+      mesh: optional jax mesh — ``run`` / ``run_batched`` then execute
+        the distributed per-chip program derived from the same
+        resolved spec (``merge``/``max_layers``) over the CSR, which
+        is partitioned over the mesh here, once.  Without it, the mesh
+        set by ``jax.set_mesh`` is used when it spans more than one
+        device.
     """
     # admission-time structural validation (ISSUE 8): raw Csr inputs
     # are checked BEFORE as_format wraps them (CsrFormat's int() ctor
@@ -333,6 +355,8 @@ def plan(graph, spec: TraversalSpec | None = None, *,
     fmt = as_format(graph)
     fmt.validate_structure()
     spec = spec if spec is not None else TraversalSpec()
+    if mesh is None and jax.sharding.get_abstract_mesh().size > 1:
+        mesh = jax.sharding.get_mesh()      # the one jax.set_mesh set
     if mesh is not None:
         # same contract as run_bfs_distributed(spec=): flag
         # explicitly-set fields the fixed per-chip program cannot
@@ -347,3 +371,14 @@ def plan(graph, spec: TraversalSpec | None = None, *,
     # run() is the shard_map program) — don't pollute the cache
     ex = None if mesh is not None else _executable(fmt, resolved)
     return CompiledTraversal(fmt, resolved, ex, batch=batch, mesh=mesh)
+
+
+def _mesh_partition(fmt, mesh):
+    """The mesh path's CSR partition (`bfs_distributed.partition_on_mesh`)."""
+    from repro.core import bfs_distributed as dist
+    to_csr = getattr(fmt, "to_csr", None)
+    if to_csr is None:
+        raise TypeError(
+            f"mesh-bound plans need a CSR-recoverable format; "
+            f"{type(fmt).__name__} has no to_csr()")
+    return dist.partition_on_mesh(to_csr(), mesh)

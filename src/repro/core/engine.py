@@ -340,23 +340,27 @@ def edge_stream(colstarts, rows, frontier_words, list_size: int,
     return apportion(colstarts, rows, frontier_list, n_vertices, n_slots)
 
 
-def rowsweep_stream(colstarts, rows, active_words, n_vertices: int,
-                    nbr_limit: int | None = None):
+def rowsweep_stream(colstarts, rows, active_words, n_vertices: int):
     """(u, v, valid) in **rows order** — the jnp form of the fused
     in-kernel gather (kernels/gather_expand.py) and its oracle.
 
     Owners come from a degree-expansion of ``colstarts`` and the
-    frontier gate is a bitmap test per edge — one pass over ``rows``
-    with no compaction, no marker scatter and no prefix-sum
-    intermediates (the apportionment machinery the fused pipeline
-    removes).  ``nbr_limit`` bounds valid neighbor ids; it differs
-    from ``n_vertices`` only in the distributed per-chip step, where
-    owners live in LOCAL ids (< v_loc) but neighbors are GLOBAL.
+    frontier gate is a bitmap test per edge (`owner_gate`) — one pass
+    over ``rows`` with no compaction, no marker scatter and no
+    prefix-sum intermediates (the apportionment machinery the fused
+    pipeline removes).
     """
-    nbr_limit = n_vertices if nbr_limit is None else nbr_limit
     u = edge_owners(colstarts, int(rows.shape[0]), n_vertices)
-    valid = bm.test_bits(active_words, u) & (rows < nbr_limit)
-    return u, rows, valid
+    return u, rows, owner_gate(u, rows, active_words, n_vertices)
+
+
+def owner_gate(owners, rows, active_words, nbr_limit: int):
+    """The per-slot gate of a rows-order stream: the slot's owner is in
+    ``active_words`` and its neighbor is a real vertex (``< nbr_limit``;
+    padding slots hold the sentinel).  The distributed per-chip step
+    passes LOCAL owner ids (< v_loc) and GLOBAL neighbors, so there
+    ``nbr_limit`` is the global vertex count."""
+    return bm.test_bits(active_words, owners) & (rows < nbr_limit)
 
 
 def edge_owners(colstarts, e_pad: int, n_vertices: int):

@@ -282,8 +282,8 @@ def lower_cell(arch: str, shape_name: str, mesh_name: str,
 def lower_bfs_cell(graph_name: str, mesh_name: str,
                    merge: str = "allreduce"):
     """Dry-run the paper's distributed BFS on the production mesh."""
-    from repro.core.bfs_distributed import (make_bfs_program,
-                                            partition_sizes)
+    from repro.core.bfs_distributed import (partition_sizes,
+                                            sharded_program)
     g = GRAPHS[graph_name]
     mesh = _mesh(mesh_name)
     axes = tuple(mesh.axis_names)
@@ -293,26 +293,19 @@ def lower_bfs_cell(graph_name: str, mesh_name: str,
     # single_layer=True: the roofline terms below are EXACT per-layer
     # costs (the full while-loop's trip count is data-dependent; the
     # compile-success proof still uses the full program)
-    program = make_bfs_program(v_loc, g.n_vertices, n_chips, axes,
-                               merge=merge, single_layer=True)
-    program_full = make_bfs_program(v_loc, g.n_vertices, n_chips, axes,
-                                    merge=merge)
-    p_out = P() if merge == "allreduce" else P(axes)
-    shard = jax.shard_map(
-        program, mesh=mesh,
-        in_specs=(P(axes), P(axes), P()), out_specs=(p_out, P()))
-    shard_full = jax.shard_map(
-        program_full, mesh=mesh,
-        in_specs=(P(axes), P(axes), P()), out_specs=(p_out, P()))
-    rows_s = jax.ShapeDtypeStruct((n_chips, e_loc), jnp.int32)
-    cs_s = jax.ShapeDtypeStruct((n_chips, v_loc + 1), jnp.int32)
+    shard = sharded_program(mesh, axes, v_loc, g.n_vertices, 64, merge,
+                            single_layer=True)
+    shard_full = sharded_program(mesh, axes, v_loc, g.n_vertices, 64,
+                                 merge)
+    # rows and their owner ids: one (n_chips, e_loc) shape
+    slots_s = jax.ShapeDtypeStruct((n_chips, e_loc), jnp.int32)
     root_s = jax.ShapeDtypeStruct((), jnp.int32)
     t0 = time.time()
     with mesh:
         # full program must compile (the dry-run proof) ...
-        jax.jit(shard_full).lower(rows_s, cs_s, root_s).compile()
+        jax.jit(shard_full).lower(slots_s, slots_s, root_s).compile()
         # ... the single-layer probe provides the roofline terms
-        lowered = jax.jit(shard).lower(rows_s, cs_s, root_s)
+        lowered = jax.jit(shard).lower(slots_s, slots_s, root_s)
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
     from repro.roofline.hlo_analyze import analyze

@@ -244,10 +244,20 @@ def test_merge_flavour_shares_single_chip_executable(g):
 def test_mesh_bound_plan_rejects_single_chip_surfaces(g):
     mesh = jax.make_mesh((1,), ("x",))
     ct = bfs.plan(g, mesh=mesh)
+    # the single-layer tick is the one single-chip surface left: the
+    # mesh program runs whole searches (run, run_batched, lower)
+    state = engine.BfsState(*engine._init_batched(
+        jnp.asarray([3], jnp.int32), g.n_vertices, g.n_vertices_padded),
+        jnp.int32(0))
     with pytest.raises(NotImplementedError):
-        ct.run_batched([3, 7])
-    with pytest.raises(NotImplementedError):
-        ct.lower()
+        ct.layer_step(state)
+    ct.lower([3, 7]).compile()
+    res = ct.run_batched([3, 7])
+    assert res.state.parent.shape[0] == 2 and res.stats is None
+    for i, root in enumerate((3, 7)):
+        check_oracle(g, np.asarray(parents_graph500(res.state,
+                                                    g.n_vertices))[i],
+                     root)
     # fields the fixed per-chip program cannot honor are flagged…
     with pytest.warns(UserWarning, match="ignored"):
         bfs.plan(g, bfs.TraversalSpec(pipeline="materialized"),
@@ -333,6 +343,20 @@ def test_distributed_spec_path_matches_legacy(g):
     with pytest.warns(UserWarning, match="ignored"):
         run_bfs_distributed(g, 11, mesh,
                             spec=bfs.TraversalSpec(packed=False))
+
+
+def test_plan_takes_only_a_multi_device_mesh_from_context(g):
+    """Under ``jax.set_mesh`` of one device, and outside any mesh
+    context, ``plan(g)`` is the single-chip plan it always was."""
+    outside = bfs.plan(g)
+    with jax.set_mesh(jax.make_mesh((1,), ("x",))):
+        inside = bfs.plan(g)
+    for ct in (outside, inside):
+        assert ct.mesh is None and ct._partition is None
+        assert ct.executable is outside.executable
+    assert inside.resolved == outside.resolved
+    p = np.asarray(parents_graph500(inside.run(17).state, g.n_vertices))
+    check_oracle(g, p, 17)
 
 
 def test_plan_mesh_binding_routes_distributed(g):

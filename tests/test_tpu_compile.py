@@ -14,7 +14,8 @@ Three groups:
 * the steps of ``pipeline="xla"`` — the one the formats declare in
   ``tpu_pipelines`` — compile whole: the serve tick and the fused
   whole-search program, each step with two gathers and one scatter
-  over the slot stream in the compiled program;
+  over the slot stream in the compiled program; so does the mesh
+  program's per-chip step over the four chips of a v5e:2x2 host;
 * every kernel refusal recorded in `repro.kernels.TPU_REFUSALS` is
   still what the compiler answers, so the declaration cannot go stale.
 
@@ -29,6 +30,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.api.plan import _Executable
@@ -175,6 +177,37 @@ def test_sell_serve_tick_compiles(sds, on_tpu):
     compiled = ex.layer_jit.lower(fmt, *_state(sds)).compile()
     _fits_hbm(compiled)
     assert _slot_stream_ops(compiled.as_text(), slots) == (2, 1)
+
+
+def test_mesh_search_compiles_with_owners_from_the_partition(topo,
+                                                             on_tpu):
+    """`plan(g, mesh)`'s per-root program (`bfs_distributed.run`) over
+    the four chips of the described v5e:2x2 host, at rmat-16 (2^16
+    vertices, edgefactor 16, both directions: 2^19 slots a chip).
+    Over each chip's slot stream, inside the layer loop: two gathers
+    (the owner's frontier word, the neighbor's visited word) and one
+    scatter.  The owners come with the partition, so the loop holds
+    neither their expansion (``jnp.repeat``: a marker scatter-add and a
+    ``_take`` gather) nor any other slot gather."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import bfs_distributed as dist
+    n_chips, v = 4, 1 << 16
+    v_loc, e_loc = v // n_chips, 32 * v // n_chips
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("chips",))
+    slots = jax.ShapeDtypeStruct((n_chips, e_loc), jnp.int32,
+                                 sharding=NamedSharding(mesh, P("chips")))
+    root = jax.ShapeDtypeStruct((), jnp.int32,
+                                sharding=NamedSharding(mesh, P()))
+    compiled = dist._search.lower(
+        slots, slots, root, mesh=mesh, axis_names=("chips",), v_loc=v_loc,
+        n_vertices=v, max_layers=64, merge="packed").compile()
+    _fits_hbm(compiled)
+    hlo = compiled.as_text()
+    assert _slot_stream_ops(hlo, e_loc) == (2, 1)
+    in_loop = [line for line in hlo.splitlines() if "/while/body/" in line]
+    assert not [line for line in in_loop
+                if "jit(_take)" in line or "scatter-add" in line]
 
 
 # ---------------------------------------------------------------------------
