@@ -36,12 +36,15 @@ map onto engine phases as follows:
   - ``materialized`` (legacy, kept for the ablation axis) — the
     apportionment machinery (`edge_stream`) writes a full-E ``(u, v,
     valid)`` stream to HBM which the kernel then re-reads.
-  - ``xla`` — the shared `expand_candidates` body as XLA ops over
-    the whole edge stream, no Pallas kernel (`make_xla_steps`): per
-    slot and layer two word gathers (the owner gate, the visited
-    test) and one parent scatter.  The TPU compiler refuses the
-    expansion kernels of the other pipelines, so on a TPU this is the
-    one the formats declare (``GraphFormat.tpu_pipelines``).
+  - ``xla`` — the shared `expand_candidates` body as XLA ops, no
+    Pallas kernel (`make_xla_steps`).  SELL sweeps its whole slot
+    stream every layer: per slot two word gathers and one parent
+    scatter.  On CSR each root-layer sweeps only the active side's
+    adjacency (the frontier's top-down, the unvisited set's
+    bottom-up), in the smallest of a ladder of static stream sizes
+    that holds it (`make_csr_xla_steps`).  The TPU compiler refuses
+    the expansion kernels of the other pipelines, so on a TPU this is
+    the one the formats declare (``GraphFormat.tpu_pipelines``).
 
   The scalar (plain-jnp) layer keeps the materialized apportionment in
   both pipelines; the batched kernels add a leading root axis so many
@@ -587,7 +590,7 @@ def _resolve_tile_csr(tile: int | None, e_pad: int, fmt=None) -> int:
 
 def expand_candidates(u, v, valid, frontier, visited, parent,
                       n_vertices: int, algorithm: str, semiring=None,
-                      vals=None):
+                      vals=None, v_unvisited: bool = False):
     """The post-gather Algorithm 2/3 body on any layout's edge stream.
 
     The single home of the test-mask-scatter(-restore) sequence:
@@ -611,6 +614,9 @@ def expand_candidates(u, v, valid, frontier, visited, parent,
     relies on ``frontier ⊆ visited`` on entry, so it does not test the
     frontier again: `init_root_state` sets the root in both bitmaps,
     and every step returns an ``out`` inside its ``visited``.
+    ``v_unvisited`` says every ``v`` of the stream is undiscovered on
+    entry (a bottom-up stream built from the unvisited set's own
+    adjacency), so the visited test, and its gather, is skipped.
     """
     v_pad = parent.shape[0]
     if semiring is not None:
@@ -631,7 +637,8 @@ def expand_candidates(u, v, valid, frontier, visited, parent,
         return bm.pack_bool(improved), new_vals, parent
     if algorithm == "nonsimd":         # Algorithm 2: exact dense updates
         vis_dense = bm.unpack_bool(visited)
-        mask = valid & ~vis_dense[jnp.clip(v, 0, v_pad - 1)]
+        mask = valid if v_unvisited \
+            else valid & ~vis_dense[jnp.clip(v, 0, v_pad - 1)]
         idx = jnp.where(mask, v, v_pad)
         parent = parent.at[idx].set(u, mode="drop")
         out_dense = (jnp.zeros((v_pad,), bool)
@@ -639,7 +646,7 @@ def expand_candidates(u, v, valid, frontier, visited, parent,
         out = bm.pack_bool(out_dense)
         return out, visited | out, parent
     # Algorithm 3: negative P marks, then restoration from them alone
-    mask = valid & ~bm.test_bits(visited, v)
+    mask = valid if v_unvisited else valid & ~bm.test_bits(visited, v)
     idx = jnp.where(mask, v, v_pad)
     parent = parent.at[idx].set(u - n_vertices, mode="drop")
     marked = parent < 0
@@ -828,10 +835,17 @@ def _make_megakernel_step(colstarts, rows_t, n_vertices: int, tile: int,
 
 
 def make_xla_steps(src, dst, n_vertices: int, algorithm: str,
-                   tiles_per_root: int) -> dict:
+                   tiles_per_root: int | None = None, *, colstarts=None,
+                   tile: int | None = None) -> dict:
     """The per-layer steps of ``pipeline="xla"``: the shared
     `expand_candidates` body, as XLA ops, over a flat (owner, neighbor)
     slot stream.
+
+    A CSR graph passes its owners compressed instead, as ``colstarts``
+    (``src`` None, ``dst`` its rows, ``tile`` the stats' tile), and
+    gets the steps of `make_csr_xla_steps`, which sweep only the
+    active side's adjacency.  What follows describes the fixed stream
+    (SELL's ``_slot_pairs``).
 
     The TPU compiler lowers none of the expansion kernels
     (`kernels.TPU_REFUSALS`), so this is the pipeline the streamed
@@ -849,6 +863,9 @@ def make_xla_steps(src, dst, n_vertices: int, algorithm: str,
     stats.  Roots run one after another (`lax.map`): a vmapped sweep
     puts the root axis minor in its (E, B) gathers, which the TPU pads
     from B to 128 lanes (34 GiB at Graph500 scale 20 with B = 8)."""
+    if colstarts is not None:
+        return make_csr_xla_steps(colstarts, dst, n_vertices, algorithm,
+                                  tile)
     real = dst < n_vertices
 
     def make_step(bottom_up: bool):
@@ -865,6 +882,156 @@ def make_xla_steps(src, dst, n_vertices: int, algorithm: str,
                 one_root, (frontier, visited, parent))
             aux = StepAux(jnp.int32(frontier.shape[0] * tiles_per_root),
                           jnp.int32(0), jnp.int32(0))
+            return out, visited, parent, aux
+        return step
+
+    top_down = make_step(bottom_up=False)
+    return {MODE_SCALAR: top_down, MODE_SIMD: top_down,
+            MODE_BOTTOMUP: make_step(bottom_up=True)}
+
+
+def _running(scan, combine, x, k: int = 1024):
+    """Inclusive running ``combine`` (`jax.lax.cumsum` with `jnp.add`,
+    or `jax.lax.cummax` with `jnp.maximum`, over values >= 0) of a 1-D
+    array, in rows of ``k``: within each row, then over the rows'
+    totals.  The TPU compiler spends up to half a minute on a 1-D
+    prefix of some lengths (deviceless v5e compiles: 25 s at 2^20
+    elements, 32 s at 2^21), and about a second on this form."""
+    n = x.shape[0]
+    if n <= k:
+        return scan(x)
+    rows = scan(jnp.pad(x, (0, -n % k)).reshape(-1, k), axis=1)
+    carry = _running(scan, combine, rows[:, -1], k)
+    before = jnp.concatenate([jnp.zeros((1,), x.dtype), carry[:-1]])
+    return combine(rows, before[:, None]).reshape(-1)[:n]
+
+
+def csr_stream_sizes(e_pad: int, n_vertices: int) -> tuple[int, ...]:
+    """The static stream sizes of `make_csr_xla_steps`, largest first:
+    ``e_pad``, then a quarter of the last while that is at least
+    ``max(V // 64, 128)``.  A stream of V/64 slots costs a few per
+    cent of the layer's own V-sized work (two scatters of one update
+    per vertex), so a smaller bucket would save nothing and add a
+    branch to compile."""
+    floor = max(n_vertices // 64, 128)
+    sizes = [e_pad]
+    while sizes[-1] // 4 >= floor:
+        sizes.append(sizes[-1] // 4)
+    return tuple(sizes)
+
+
+def make_csr_xla_steps(colstarts, rows, n_vertices: int, algorithm: str,
+                       tile: int) -> dict:
+    """The CSR steps of ``pipeline="xla"``: per root and layer, the
+    shared `expand_candidates` body over the active side's adjacency,
+    in a stream sized from its own slot count.
+
+    The active set is the frontier top-down, and bottom-up the
+    unvisited set (``~visited``: padding is premarked, so the word
+    complement is the real undiscovered set), empty where the root's
+    frontier is.  Its slot count ``n`` picks the smallest size of
+    `csr_stream_sizes` that holds it (`lax.switch`).
+
+    Below the largest size the stream is the active adjacency alone,
+    compacted in CSR order.  CSR rows are sorted by owner, so that
+    adjacency is a run of contiguous ranges.  With ``start`` the
+    exclusive prefix sum of the active degrees, one V-sized
+    max-scatter each marks, at every active vertex's ``start``, its id
+    and the count of inactive slots before it; a running max over the
+    stream carries both to every slot (both grow with the vertex id).
+    Slot ``s`` reads ``rows[s + skipped]``, and slots from ``n`` on are
+    invalid: no owner array and no per-slot search.  Top-down the owner
+    is in the frontier, so no gate is read; bottom-up the gate is the
+    neighbor's frontier bit and the owner is known undiscovered
+    (``v_unvisited``).  Per slot: the ``rows`` gather, one word gather
+    and one parent scatter.
+
+    The largest size sweeps every slot in place, as `make_xla_steps`
+    does: on a v5e a compacted slot costs about three times a swept one
+    (the ``rows`` gather), so past a quarter of the slots the sweep is
+    cheaper.  Its owners are marked the same way, for every vertex,
+    each with its active bit beside it (``2 * id + bit``, also growing
+    with the id), so the owner's side is gated without a gather.  Per
+    slot: one word gather (the neighbor's visited word top-down, its
+    frontier word bottom-up) and one parent scatter.
+
+    Either stream is an order-preserving subsequence of the full
+    sweep's (`make_xla_steps` over `edge_owners`) that holds every slot
+    able to write a parent, so where duplicate scatter updates apply in
+    stream order the parents equal the full sweep's.  The stats count
+    the ``tile``-sized tiles of the stream each root swept.  Roots run
+    one after another (`lax.map`), as in `make_xla_steps`."""
+    if n_vertices >= 2**30:
+        raise ValueError(f"n_vertices={n_vertices}: the sweep tags each "
+                         f"owner as 2 * id + bit in int32, which needs "
+                         f"n_vertices < 2**30")
+    e_pad = int(rows.shape[0])
+    sizes = csr_stream_sizes(e_pad, n_vertices)
+    bucket_tiles = jnp.asarray([-(-s // tile) for s in sizes], jnp.int32)
+    deg = colstarts[1:] - colstarts[:-1]
+    ids = jnp.arange(n_vertices, dtype=jnp.int32)
+    real = rows < n_vertices
+
+    def spread(size, start, marks):
+        """Each mark carried from its ``start`` to the stream's later
+        slots."""
+        return _running(jax.lax.cummax, jnp.maximum,
+                        jnp.zeros((size,), jnp.int32).at[start]
+                        .max(marks, mode="drop", indices_are_sorted=True))
+
+    def make_step(bottom_up: bool):
+        def sweep(frontier, visited, parent, active, *_):
+            tagged = spread(e_pad, colstarts[:-1],
+                            2 * ids + active.astype(jnp.int32))
+            owner, live = tagged >> 1, real & (tagged & 1 == 1)
+            if bottom_up:
+                return expand_candidates(
+                    rows, owner, live & bm.test_bits(frontier, rows),
+                    frontier, visited, parent, n_vertices, algorithm,
+                    v_unvisited=True)
+            return expand_candidates(owner, rows, live, frontier, visited,
+                                     parent, n_vertices, algorithm)
+
+        def compacted(size: int):
+            def run(frontier, visited, parent, active, start, marks, n):
+                slot = jnp.arange(size, dtype=jnp.int32)
+                owner, skipped = (spread(size, start, m) for m in marks)
+                nbr = rows[jnp.minimum(slot + skipped, e_pad - 1)]
+                valid = slot < n
+                if bottom_up:
+                    return expand_candidates(
+                        nbr, owner, valid & bm.test_bits(frontier, nbr),
+                        frontier, visited, parent, n_vertices, algorithm,
+                        v_unvisited=True)
+                return expand_candidates(owner, nbr, valid, frontier,
+                                         visited, parent, n_vertices,
+                                         algorithm)
+            return run
+
+        branches = [sweep] + [compacted(size) for size in sizes[1:]]
+
+        def one_root(state):
+            frontier, visited, parent = state
+            active = bm.unpack_bool(
+                ~visited if bottom_up else frontier)[:n_vertices]
+            a_deg = jnp.where(active, deg, 0)
+            end = _running(jax.lax.cumsum, jnp.add, a_deg)
+            start = end - a_deg
+            n = end[-1]
+            if bottom_up:
+                n = jnp.where(jnp.any(frontier != 0), n, 0)
+            marks = (jnp.where(active, ids, 0),
+                     jnp.where(active, colstarts[:-1] - start, 0))
+            k = sum((n <= s).astype(jnp.int32) for s in sizes) - 1
+            out = jax.lax.switch(k, branches, frontier, visited, parent,
+                                 active, start, marks, n)
+            return out, bucket_tiles[k]
+
+        def step(frontier, visited, parent):
+            (out, visited, parent), tiles = jax.lax.map(
+                one_root, (frontier, visited, parent))
+            aux = StepAux(tiles.sum(dtype=jnp.int32), jnp.int32(0),
+                          jnp.int32(0))
             return out, visited, parent, aux
         return step
 
@@ -939,11 +1106,16 @@ def check_pipeline(pipeline: str) -> None:
 def _make_steps(colstarts, rows, n_vertices, v_pad, e_pad, algorithm,
                 tile, pipeline: str = "fused_gather",
                 packed: bool = True, prefetch_depth: int = 0):
+    """The CSR format's per-layer steps for ``pipeline``, keyed by
+    engine mode (`formats.CsrFormat._build_steps`).  ``"xla"`` sweeps
+    the active side's adjacency only, in a stream bucketed from each
+    root-layer's own slot count (`make_csr_xla_steps`); the Pallas
+    pipelines plan active tiles or apportion the frontier's
+    adjacency."""
     check_pipeline(pipeline)
     if pipeline == "xla":
-        return make_xla_steps(edge_owners(colstarts, e_pad, n_vertices),
-                              rows, n_vertices, algorithm,
-                              -(-e_pad // tile))
+        return make_xla_steps(None, rows, n_vertices, algorithm,
+                              colstarts=colstarts, tile=tile)
     # the persistent pipeline's PER-LAYER steps (the serve tier's
     # layer_step tick) are the megakernel steps — whole-traversal
     # queries never reach here (they route through

@@ -14,7 +14,8 @@ Three groups:
 * the steps of ``pipeline="xla"`` — the one the formats declare in
   ``tpu_pipelines`` — compile whole: the serve tick and the fused
   whole-search program, each step with two gathers and one scatter
-  over the slot stream in the compiled program; so does the mesh
+  over the slot stream (on CSR, over each bucketed stream) in the
+  compiled program; so does the mesh
   program's per-chip step over the four chips of a v5e:2x2 host;
 * every kernel refusal recorded in `repro.kernels.TPU_REFUSALS` is
   still what the compiler answers, so the declaration cannot go stale.
@@ -157,13 +158,23 @@ def _slot_stream_ops(hlo: str, n_slots: int):
 
 def test_csr_whole_search_compiles(sds, on_tpu):
     """`CompiledTraversal.run_batched`'s program: the Beamer layer loop
-    over both xla steps (the policy ``auto`` picks on RMAT)."""
+    over both xla steps (the policy ``auto`` picks on RMAT).  Each step
+    sweeps one of the streams of `engine.csr_stream_sizes`,
+    and builds it without an owner array (no ``jnp.repeat``, whose
+    gather is ``jit(_take)``)."""
     fmt = CsrFormat(sds((V + 1,)), sds((E,)), V, E)
     ex = _Executable(_spec(engine.BeamerHybrid()))
     compiled = ex.run_jit.lower(fmt, sds((B,))).compile()
     _fits_hbm(compiled)
-    # two steps (top-down, bottom-up), each 2 gathers + 1 scatter
-    assert _slot_stream_ops(compiled.as_text(), E) == (4, 2)
+    hlo = compiled.as_text()
+    sizes = engine.csr_stream_sizes(E, V)
+    assert sizes == tuple(E >> 2 * k for k in range(6))
+    # per size two steps (top-down, bottom-up): the largest sweeps the
+    # rows in place, 1 gather (a word) + 1 scatter (the parent); each
+    # smaller one 2 gathers (the rows, a word) + 1 scatter
+    assert {s: _slot_stream_ops(hlo, s) for s in sizes} \
+        == {s: (4, 2) if s < E else (2, 2) for s in sizes}
+    assert "jit(_take)" not in hlo
 
 
 def test_sell_serve_tick_compiles(sds, on_tpu):

@@ -170,6 +170,123 @@ def test_xla_beamer_layers_keep_frontier_inside_visited(graphs, fmt_name):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _from_pairs(pairs, n):
+    """Symmetric CSR of undirected ``pairs`` over ``n`` vertices."""
+    a, b = np.asarray(pairs, np.int32).T
+    return csr_mod.from_edges(EdgeList(jnp.asarray(np.r_[a, b]),
+                                       jnp.asarray(np.r_[b, a]), n))
+
+
+def _star(leaves, extra_path=0):
+    """Hub 0 and ``leaves`` leaves, beside a path of ``extra_path``
+    more vertices that no root of the star reaches."""
+    pairs = [(0, i) for i in range(1, leaves + 1)]
+    first = leaves + 1
+    pairs += [(first + i, first + i + 1) for i in range(extra_path - 1)]
+    return _from_pairs(pairs, first + extra_path)
+
+
+def _disconnected():
+    """R-MAT 9 on the even ids, a 64-cycle on the first odd ids, and
+    every other odd id isolated: zero-degree vertices sit among the
+    unvisited set of every bottom-up layer, and one is a root."""
+    g = rmat.generate(jax.random.PRNGKey(1), scale=9)
+    ring = 2 * np.arange(64) + 1
+    src = np.r_[2 * np.asarray(g.src), ring, np.roll(ring, 1)]
+    dst = np.r_[2 * np.asarray(g.dst), np.roll(ring, 1), ring]
+    return csr_mod.from_edges(EdgeList(jnp.asarray(src, jnp.int32),
+                                       jnp.asarray(dst, jnp.int32),
+                                       2 * g.n_vertices))
+
+
+def _rmat(scale):
+    return csr_mod.from_edges(rmat.generate(jax.random.PRNGKey(0),
+                                            scale=scale))
+
+
+#: name -> (graph, roots, spec fields); ``csr_stream_sizes`` of each is
+#: in the comment
+CSR_SEARCHES = {
+    "rmat9": (lambda: _rmat(9), [1, 2, 3, 5], {}),
+    "rmat10": (lambda: _rmat(10), [1, 2, 3, 5], {}),
+    "rmat11": (lambda: _rmat(11), [1, 2, 3, 5], {}),
+    # 4096, 1024, 256: the hub's bottom-up layer fills the top bucket
+    "star": (lambda: _star(2047), [0, 5], {"tile": 128}),
+    # 2048, 512, 128: every layer holds at most four slots
+    "path": (lambda: _path_graph(1024), [0, 511],
+             {"tile": 128, "max_layers": 1100}),
+    "disconnected": (_disconnected, [0, 301, 7], {}),
+    # 4096, 1024, 256: the hub's top-down layer holds exactly 256
+    # slots (the unreached path keeps Beamer top-down)
+    "exact_bucket": (lambda: _star(256, extra_path=1793), [0],
+                     {"tile": 128}),
+}
+
+
+@pytest.fixture(scope="module")
+def csr_searches():
+    return {name: (make(), roots, kw)
+            for name, (make, roots, kw) in CSR_SEARCHES.items()}
+
+
+def _planned_search(csr_searches, name):
+    g, roots, kw = csr_searches[name]
+    ct = bfs.plan(g, bfs.TraversalSpec(pipeline="xla", policy="beamer",
+                                       **kw))
+    roots = jnp.asarray(roots, jnp.int32)
+    return g, roots, ct, ct.run_batched(roots)
+
+
+@pytest.mark.parametrize("name", list(CSR_SEARCHES))
+def test_csr_xla_step_matches_the_full_sweep(csr_searches, name):
+    """The CSR step sweeps the active side's adjacency only.  Replayed
+    layer by layer, the full sweep over every slot (`make_xla_steps`
+    over `edge_owners`) gives the whole-search program's state bit for
+    bit, and every tree has `bfs_serial`'s reach set and depths."""
+    g, roots, ct, res = _planned_search(csr_searches, name)
+    n = g.n_vertices
+    full = engine.make_xla_steps(
+        engine.edge_owners(g.colstarts, g.n_edges_padded, n), g.rows, n,
+        "simd", 1)
+    full = {m: jax.jit(step) for m, step in full.items()}
+    state = engine._init_batched(roots, n, g.n_vertices_padded)
+    for direction in engine.direction_log(res):
+        mode = (engine.MODE_BOTTOMUP if direction == "bottomup"
+                else engine.MODE_SIMD)
+        state = full[mode](*state)[:3]
+    for got, want in zip(res.state[:3], state):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    parents = bfs.parents_graph500(res.state, n)
+    for i, root in enumerate(np.asarray(roots).tolist()):
+        ref = bfs_serial(np.asarray(g.rows), np.asarray(g.colstarts), n,
+                         root)[1]
+        assert validate(g, parents[i], root, reference_depth=ref).ok
+
+
+@pytest.mark.parametrize("name", ["path", "star", "exact_bucket"])
+def test_csr_xla_stats_count_the_swept_bucket(csr_searches, name):
+    """`LayerStats.active_tiles` is the tile count of the bucket each
+    root swept: the floor bucket on every layer of a path, the top one
+    on a hub's first bottom-up layer, and a bucket exactly as large as
+    the hub's 256 slots."""
+    g, roots, ct, res = _planned_search(csr_searches, name)
+    sizes = engine.csr_stream_sizes(g.n_edges_padded, g.n_vertices)
+    tiles = [-(-s // ct.resolved.tile) for s in sizes]
+    stats = engine.layer_stats(res)
+    log = engine.direction_log(res)
+    n_roots = len(roots)
+    if name == "path":
+        assert len(sizes) == 3 and len(stats) > 1000
+        assert {s.active_tiles for s in stats} == {n_roots * tiles[-1]}
+    elif name == "star":
+        assert stats[log.index("bottomup")].active_tiles \
+            == n_roots * tiles[0]
+    else:
+        assert (log[0], stats[0].edges_examined) == ("topdown", sizes[-1])
+        assert stats[0].active_tiles == n_roots * tiles[-1]
+        assert len(set(tiles)) == len(tiles)
+
+
 def _run_smoke(cwd, script):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run([sys.executable, str(script)], cwd=cwd,
